@@ -14,6 +14,7 @@ from repro.configs import get_config, get_tiny
 from repro.configs.base import TrainConfig
 from repro.core import Response, detect_recover
 from repro.data.synthetic import batch_stream
+from repro.launch.workdir import CKPT_ROOT
 from repro.runtime.train_loop import LoopConfig, run_training
 
 
@@ -36,7 +37,7 @@ def main():
     policy = detect_recover()
     object.__setattr__(policy, "scrub_interval", 10)
 
-    ckpt = "/tmp/repro_train_hrm"
+    ckpt = str(CKPT_ROOT / "repro_train_hrm")
     shutil.rmtree(ckpt, ignore_errors=True)
     loop = LoopConfig(
         steps=steps,

@@ -49,7 +49,7 @@ from repro.core.recovery import (Response, RestartRequired, RetirementMap,
                                  flagged_blocks)
 from repro.core.sidecar import ScrubReport, _path_str
 from repro.core.tiers import Tier
-from repro.kernels import ops
+from repro.kernels import interpret_mode, ops
 from repro.kernels.burst import burst_encode_words, burst_scrub_words
 from repro.kernels.dected import dected_encode_words, dected_scrub_words
 from repro.kernels.ops import BLOCK_ROWS, LANES, _round_rows
@@ -209,7 +209,7 @@ def _block_rows(padded: int) -> int:
     VMEM tile is the right block; in interpret mode (CPU) the emulator
     re-materializes every operand per grid step, so one grid step over the
     whole buffer is the fast path."""
-    return padded if ops.INTERPRET else min(BLOCK_ROWS, padded)
+    return padded if interpret_mode() else min(BLOCK_ROWS, padded)
 
 
 def _scrub_tier_buf(tier: Tier, lo, hi, pull, push, bm: int):
@@ -223,28 +223,23 @@ def _scrub_tier_buf(tier: Tier, lo, hi, pull, push, bm: int):
     """
     if tier is Tier.SECDED:
         lo2, hi2, ecc2, c, u = secded_scrub_words(
-            lo, hi, pull("ecc", jnp.uint32), block_rows=bm,
-            interpret=ops.INTERPRET)
+            lo, hi, pull("ecc", jnp.uint32), block_rows=bm)
         push("ecc", ecc2, jnp.uint8)
     elif tier is Tier.DECTED:
         lo2, hi2, ecc2, c, u = dected_scrub_words(
-            lo, hi, pull("ecc", jnp.uint32), block_rows=bm,
-            interpret=ops.INTERPRET)
+            lo, hi, pull("ecc", jnp.uint32), block_rows=bm)
         push("ecc", ecc2, jnp.uint16)
     elif tier is Tier.BURST:
         lo2, hi2, ecc2, c, u = burst_scrub_words(
-            lo, hi, pull("ecc", jnp.uint32), block_rows=bm,
-            interpret=ops.INTERPRET)
+            lo, hi, pull("ecc", jnp.uint32), block_rows=bm)
         push("ecc", ecc2, jnp.uint16)
     elif tier is Tier.PARITY_R:
         _err, cnt = parity_check_words(
-            lo, hi, pull("par", jnp.uint32), block_rows=bm,
-            interpret=ops.INTERPRET)
+            lo, hi, pull("par", jnp.uint32), block_rows=bm)
         return lo, hi, jnp.zeros_like(cnt), cnt, False
     elif tier is Tier.MIRROR:
         err, _ = parity_check_words(
-            lo, hi, pull("par", jnp.uint32), block_rows=bm,
-            interpret=ops.INTERPRET)
+            lo, hi, pull("par", jnp.uint32), block_rows=bm)
         mask = _parity_mask(err, lo)
         lo2 = jnp.where(mask, pull("copy_lo"), lo)
         hi2 = jnp.where(mask, pull("copy_hi"), hi)
@@ -404,25 +399,20 @@ def _compiled_encode(spec: DomainSpec, key: Optional[Tuple[str, ...]]
         lo, hi = _gather_packed(leaves, sel, padded)
         if tier is Tier.SECDED:
             return {"ecc": secded_encode_words(
-                lo, hi, block_rows=bm,
-                interpret=ops.INTERPRET).astype(jnp.uint8)}
+                lo, hi, block_rows=bm).astype(jnp.uint8)}
         if tier is Tier.DECTED:
             return {"ecc": dected_encode_words(
-                lo, hi, block_rows=bm,
-                interpret=ops.INTERPRET).astype(jnp.uint16)}
+                lo, hi, block_rows=bm).astype(jnp.uint16)}
         if tier is Tier.BURST:
             return {"ecc": burst_encode_words(
-                lo, hi, block_rows=bm,
-                interpret=ops.INTERPRET).astype(jnp.uint16)}
+                lo, hi, block_rows=bm).astype(jnp.uint16)}
         if tier is Tier.PARITY_R:
             return {"par": parity_encode_words(
-                lo, hi, block_rows=bm,
-                interpret=ops.INTERPRET).astype(jnp.uint8)}
+                lo, hi, block_rows=bm).astype(jnp.uint8)}
         if tier is Tier.MIRROR:
             return {"copy_lo": lo, "copy_hi": hi,
                     "par": parity_encode_words(
-                        lo, hi, block_rows=bm,
-                        interpret=ops.INTERPRET).astype(jnp.uint8)}
+                        lo, hi, block_rows=bm).astype(jnp.uint8)}
         raise ValueError(tier)
 
     if not partial:

@@ -35,7 +35,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.tiers import Tier
-from repro.kernels import ops
 from repro.kernels.burst import burst_encode_words, burst_scrub_words
 from repro.kernels.dected import dected_encode_words, dected_scrub_words
 from repro.kernels.ops import LANES
@@ -107,7 +106,7 @@ def measure_class_rates(tier: Tier, strike: str, n_events: int = 128,
     words, bits = _strike(rng, rows, strike)
     blo, bhi = _flip(lo, hi, words, bits)
     jblo, jbhi = jnp.asarray(blo), jnp.asarray(bhi)
-    kw = dict(block_rows=rows, interpret=ops.INTERPRET)
+    kw = dict(block_rows=rows)
 
     if tier is Tier.NONE:
         return TierOutcomeRates(0.0, 0.0, 1.0)

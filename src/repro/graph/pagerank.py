@@ -1,7 +1,7 @@
 """PageRank over the tiled-CSR payload — push SpMV per iteration via the
 Pallas segment-sum kernels (``repro.kernels.segsum``), with a
-``jax.ops.segment_sum`` reference path and an eager jnp oracle for
-bit-equivalence testing.
+``jax.ops.segment_sum`` reference path and an eager jnp oracle that
+replays the kernel's tile math (agreement to a few f32 ulp).
 
 The iterate is the classic damped power iteration
 

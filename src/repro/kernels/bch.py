@@ -45,6 +45,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
+
 _POP = jax.lax.population_count
 
 # x^m + ... primitive over GF(2); value includes the x^m bit.
@@ -330,7 +332,7 @@ def _row_spec(bm: int, w: int):
 @functools.partial(jax.jit, static_argnames=("code", "block_rows",
                                              "interpret"))
 def bch_encode_words(lo, hi, *, code: BCHCode, block_rows: int = 128,
-                     interpret: bool = True):
+                     interpret=None):
     """lo, hi: (M, W) uint32 -> ecc (M, W) uint32 (r valid bits)."""
     m, w = lo.shape
     bm = min(block_rows, m)
@@ -341,14 +343,14 @@ def bch_encode_words(lo, hi, *, code: BCHCode, block_rows: int = 128,
         in_specs=[_row_spec(bm, w)] * 2,
         out_specs=_row_spec(bm, w),
         out_shape=jax.ShapeDtypeStruct((m, w), jnp.uint32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(lo, hi)
 
 
 @functools.partial(jax.jit, static_argnames=("code", "block_rows",
                                              "interpret"))
 def bch_scrub_words(lo, hi, ecc, *, code: BCHCode, block_rows: int = 128,
-                    interpret: bool = True):
+                    interpret=None):
     """Scrub/correct. Returns (lo', hi', ecc', corr (M,1), unc (M,1))."""
     m, w = lo.shape
     bm = min(block_rows, m)
@@ -366,5 +368,5 @@ def bch_scrub_words(lo, hi, ecc, *, code: BCHCode, block_rows: int = 128,
         in_specs=[_row_spec(bm, w)] * 3,
         out_specs=(_row_spec(bm, w),) * 3 + (_row_spec(bm, 1),) * 2,
         out_shape=outs,
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(lo, hi, ecc)

@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
+
 
 def _flip_kernel(idx_ref, bit_ref, lo_ref, hi_ref, lo_out, hi_out, *, w):
     m = pl.program_id(0)
@@ -43,7 +45,7 @@ def _flip_kernel(idx_ref, bit_ref, lo_ref, hi_ref, lo_out, hi_out, *, w):
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def bitflip_words(lo, hi, word_idx, bit_idx, *, block_rows: int = 128,
-                  interpret: bool = True):
+                  interpret=None):
     """lo, hi: (M, W) uint32; word_idx/bit_idx: (E,) int32 -> flipped lo, hi."""
     m, w = lo.shape
     bm = min(block_rows, m)
@@ -60,5 +62,5 @@ def bitflip_words(lo, hi, word_idx, bit_idx, *, block_rows: int = 128,
         in_specs=[full, full, row, row],
         out_specs=(row, row),
         out_shape=outs,
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(word_idx, bit_idx, lo, hi)

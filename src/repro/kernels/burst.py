@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import bch
+from repro.kernels import bch, interpret_mode
 
 SUB_CODE = bch.make_code(k=32, t=1, m=6, parity=True)
 N_SUB = SUB_CODE.r                             # 7 check bits per sub-code
@@ -135,7 +135,7 @@ def _row_spec(bm: int, w: int):
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def burst_encode_words(lo, hi, *, block_rows: int = 128,
-                       interpret: bool = True):
+                       interpret=None):
     """lo, hi: (M, W) uint32 -> ecc (M, W) uint32 (14 valid bits)."""
     m, w = lo.shape
     bm = min(block_rows, m)
@@ -146,13 +146,13 @@ def burst_encode_words(lo, hi, *, block_rows: int = 128,
         in_specs=[_row_spec(bm, w)] * 2,
         out_specs=_row_spec(bm, w),
         out_shape=jax.ShapeDtypeStruct((m, w), jnp.uint32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(lo, hi)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def burst_scrub_words(lo, hi, ecc, *, block_rows: int = 128,
-                      interpret: bool = True):
+                      interpret=None):
     """Scrub/correct. Returns (lo', hi', ecc', corr (M,1), unc (M,1))."""
     m, w = lo.shape
     bm = min(block_rows, m)
@@ -170,5 +170,5 @@ def burst_scrub_words(lo, hi, ecc, *, block_rows: int = 128,
         in_specs=[_row_spec(bm, w)] * 3,
         out_specs=(_row_spec(bm, w),) * 3 + (_row_spec(bm, 1),) * 2,
         out_shape=outs,
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(lo, hi, ecc)

@@ -24,7 +24,7 @@ N_CHECK = DECTED_CODE.r                        # 15
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def dected_encode_words(lo, hi, *, block_rows: int = 128,
-                        interpret: bool = True):
+                        interpret=None):
     """lo, hi: (M, W) uint32 -> ecc (M, W) uint32 (15 valid bits)."""
     return bch.bch_encode_words(lo, hi, code=DECTED_CODE,
                                 block_rows=block_rows, interpret=interpret)
@@ -32,7 +32,7 @@ def dected_encode_words(lo, hi, *, block_rows: int = 128,
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def dected_scrub_words(lo, hi, ecc, *, block_rows: int = 128,
-                       interpret: bool = True):
+                       interpret=None):
     """Scrub/correct. Returns (lo', hi', ecc', corr (M,1), unc (M,1))."""
     return bch.bch_scrub_words(lo, hi, ecc, code=DECTED_CODE,
                                block_rows=block_rows, interpret=interpret)

@@ -3,8 +3,8 @@
 Handles packing arbitrary tensors (f32 / bf16 / f16 / i32 / u32 / i8 / u8)
 into the (M, W)-shaped uint32 word-lane layout the kernels consume, and
 unpacking corrected data back to the original shape/dtype. On CPU the
-kernels run in ``interpret=True`` mode (Python-level execution of the same
-kernel body) — TPU is the compile target.
+kernels run in the Pallas interpreter (``repro.kernels.interpret_mode``) —
+TPU is the compile target.
 """
 from __future__ import annotations
 
@@ -21,51 +21,8 @@ from repro.kernels import dected as _dected
 from repro.kernels import parity as _parity
 from repro.kernels import secded as _secded
 
-INTERPRET = jax.default_backend() == "cpu"
 LANES = 256          # words per packed row; multiple of the 128-lane tile
 BLOCK_ROWS = 128
-
-
-def _u32_view(x: jax.Array) -> jax.Array:
-    """Flatten + bitcast any supported tensor to a flat uint32 vector."""
-    x = x.reshape(-1)
-    nbits = x.dtype.itemsize * 8
-    if nbits == 32:
-        return jax.lax.bitcast_convert_type(x, jnp.uint32)
-    if nbits == 16:
-        u = jax.lax.bitcast_convert_type(x, jnp.uint16).astype(jnp.uint32)
-        if u.shape[0] % 2:
-            u = jnp.pad(u, (0, 1))
-        u = u.reshape(-1, 2)
-        return u[:, 0] | (u[:, 1] << 16)
-    if nbits == 8:
-        u = jax.lax.bitcast_convert_type(x, jnp.uint8).astype(jnp.uint32)
-        pad = (-u.shape[0]) % 4
-        if pad:
-            u = jnp.pad(u, (0, pad))
-        u = u.reshape(-1, 4)
-        return (u[:, 0] | (u[:, 1] << 8) | (u[:, 2] << 16)
-                | (u[:, 3] << 24))
-    raise TypeError(f"unsupported dtype {x.dtype}")
-
-
-def _u32_unview(u: jax.Array, shape, dtype) -> jax.Array:
-    n = int(np.prod(shape)) if shape else 1
-    nbits = jnp.dtype(dtype).itemsize * 8
-    if nbits == 32:
-        flat = jax.lax.bitcast_convert_type(u, jnp.dtype(dtype))
-    elif nbits == 16:
-        lo = (u & 0xFFFF).astype(jnp.uint16)
-        hi = (u >> 16).astype(jnp.uint16)
-        flat = jax.lax.bitcast_convert_type(
-            jnp.stack([lo, hi], axis=-1).reshape(-1), jnp.dtype(dtype))
-    elif nbits == 8:
-        parts = [((u >> (8 * k)) & 0xFF).astype(jnp.uint8) for k in range(4)]
-        flat = jax.lax.bitcast_convert_type(
-            jnp.stack(parts, axis=-1).reshape(-1), jnp.dtype(dtype))
-    else:
-        raise TypeError(dtype)
-    return flat[:n].reshape(shape)
 
 
 class Packed(NamedTuple):
@@ -82,24 +39,62 @@ def _round_rows(rows: int) -> int:
     return rows
 
 
+_UINT = {1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32}
+
+
+def _lanes_per_word(dtype) -> Tuple[int, int]:
+    """(element bits, elements per 64-bit word) of a supported dtype."""
+    size = jnp.dtype(dtype).itemsize
+    if size not in _UINT:
+        raise TypeError(f"unsupported dtype {dtype}")
+    return 8 * size, 8 // size
+
+
 def pack_words(x: jax.Array) -> Packed:
-    """Tensor -> (lo, hi) word lanes, zero-padded to full (M, LANES) rows."""
-    u = _u32_view(x)
-    if u.shape[0] % 2:
-        u = jnp.pad(u, (0, 1))
-    pairs = u.reshape(-1, 2)                      # (n64, 2)
-    n64 = pairs.shape[0]
-    rows = _round_rows(-(-n64 // LANES))
-    pad = rows * LANES - n64
+    """Tensor -> (lo, hi) word lanes, zero-padded to full (M, LANES) rows.
+
+    Word ``w`` holds the tensor's flat bytes ``8w .. 8w+7``, little-endian:
+    ``lo`` the first four, ``hi`` the last four. The elements are gathered
+    by strided slices of a lane-dense (M, m * LANES) view: on a TPU, any
+    intermediate with a narrow minor dimension (an (n, 2) pair array, say)
+    is padded to 128 lanes and can outgrow the device memory.
+
+    The integer view comes before any other op: XLA rewrites the NaN
+    payloads and subnormals of 16-bit floats in ``pad`` on CPU, and on a
+    TPU (measured on a v5e) already in the bitcast itself, so there those
+    bit patterns do not survive a pack/unpack."""
+    bits, m = _lanes_per_word(x.dtype)
+    flat = jax.lax.bitcast_convert_type(x, _UINT[bits // 8]).reshape(-1)
+    rows = _round_rows(-(-flat.shape[0] // (m * LANES)))
+    pad = rows * LANES * m - flat.shape[0]
     if pad:
-        pairs = jnp.pad(pairs, ((0, pad), (0, 0)))
-    pairs = pairs.reshape(rows, LANES, 2)
-    return Packed(pairs[..., 0], pairs[..., 1])
+        flat = jnp.pad(flat, (0, pad))
+    view = flat.reshape(rows, m * LANES)
+
+    def half(first):
+        out = view[:, first::m].astype(jnp.uint32)
+        for j in range(1, m // 2):
+            out = out | (view[:, first + j::m].astype(jnp.uint32)
+                         << (bits * j))
+        return out
+
+    return Packed(half(0), half(m // 2))
 
 
 def unpack_words(p: Packed, shape, dtype) -> jax.Array:
-    pairs = jnp.stack([p.lo, p.hi], axis=-1).reshape(-1, 2)
-    return _u32_unview(pairs.reshape(-1), shape, dtype)
+    """Inverse of ``pack_words``: the elements are scattered back into a
+    lane-dense (M, m * LANES) view of the element width (see
+    ``pack_words`` for why)."""
+    bits, m = _lanes_per_word(dtype)
+    uint = _UINT[bits // 8]
+    view = jnp.zeros((p.lo.shape[0], m * LANES), uint)
+    for h, word in enumerate((p.lo, p.hi)):
+        for j in range(m // 2):
+            part = word >> (bits * j) if j else word
+            view = view.at[:, h * (m // 2) + j::m].set(part.astype(uint))
+    n = int(np.prod(shape)) if shape else 1
+    return jax.lax.bitcast_convert_type(view.reshape(-1)[:n].reshape(shape),
+                                        dtype)
 
 
 def words_per_tensor(x) -> int:
@@ -118,8 +113,8 @@ def _bm(m: int) -> int:
 def secded_encode(x: jax.Array) -> jax.Array:
     """ECC sidecar for tensor ``x``: (M, LANES) uint8 (12.5% capacity)."""
     p = pack_words(x)
-    ecc = _secded.secded_encode_words(p.lo, p.hi, block_rows=_bm(p.lo.shape[0]),
-                                      interpret=INTERPRET)
+    ecc = _secded.secded_encode_words(p.lo, p.hi,
+                                      block_rows=_bm(p.lo.shape[0]))
     return ecc.astype(jnp.uint8)
 
 
@@ -132,8 +127,7 @@ def secded_scrub(x: jax.Array, ecc: jax.Array
     """
     p = pack_words(x)
     lo, hi, ecc2, corr, unc = _secded.secded_scrub_words(
-        p.lo, p.hi, ecc.astype(jnp.uint32), block_rows=_bm(p.lo.shape[0]),
-        interpret=INTERPRET)
+        p.lo, p.hi, ecc.astype(jnp.uint32), block_rows=_bm(p.lo.shape[0]))
     x2 = unpack_words(Packed(lo, hi), x.shape, x.dtype)
     return x2, ecc2.astype(jnp.uint8), jnp.sum(corr), jnp.sum(unc)
 
@@ -144,8 +138,7 @@ def dected_encode(x: jax.Array) -> jax.Array:
     15 valid code bits per 64-bit word)."""
     p = pack_words(x)
     ecc = _dected.dected_encode_words(p.lo, p.hi,
-                                      block_rows=_bm(p.lo.shape[0]),
-                                      interpret=INTERPRET)
+                                      block_rows=_bm(p.lo.shape[0]))
     return ecc.astype(jnp.uint16)
 
 
@@ -158,8 +151,7 @@ def dected_scrub(x: jax.Array, ecc: jax.Array
     """
     p = pack_words(x)
     lo, hi, ecc2, corr, unc = _dected.dected_scrub_words(
-        p.lo, p.hi, ecc.astype(jnp.uint32), block_rows=_bm(p.lo.shape[0]),
-        interpret=INTERPRET)
+        p.lo, p.hi, ecc.astype(jnp.uint32), block_rows=_bm(p.lo.shape[0]))
     x2 = unpack_words(Packed(lo, hi), x.shape, x.dtype)
     return x2, ecc2.astype(jnp.uint16), jnp.sum(corr), jnp.sum(unc)
 
@@ -170,8 +162,7 @@ def burst_encode(x: jax.Array) -> jax.Array:
     14 valid code bits per 64-bit word)."""
     p = pack_words(x)
     ecc = _burst.burst_encode_words(p.lo, p.hi,
-                                    block_rows=_bm(p.lo.shape[0]),
-                                    interpret=INTERPRET)
+                                    block_rows=_bm(p.lo.shape[0]))
     return ecc.astype(jnp.uint16)
 
 
@@ -184,8 +175,7 @@ def burst_scrub(x: jax.Array, ecc: jax.Array
     """
     p = pack_words(x)
     lo, hi, ecc2, corr, unc = _burst.burst_scrub_words(
-        p.lo, p.hi, ecc.astype(jnp.uint32), block_rows=_bm(p.lo.shape[0]),
-        interpret=INTERPRET)
+        p.lo, p.hi, ecc.astype(jnp.uint32), block_rows=_bm(p.lo.shape[0]))
     x2 = unpack_words(Packed(lo, hi), x.shape, x.dtype)
     return x2, ecc2.astype(jnp.uint16), jnp.sum(corr), jnp.sum(unc)
 
@@ -195,8 +185,7 @@ def parity_encode(x: jax.Array) -> jax.Array:
     """Packed parity sidecar: (M, LANES//8) uint8 (1.6% capacity)."""
     p = pack_words(x)
     par = _parity.parity_encode_words(p.lo, p.hi,
-                                      block_rows=_bm(p.lo.shape[0]),
-                                      interpret=INTERPRET)
+                                      block_rows=_bm(p.lo.shape[0]))
     return par.astype(jnp.uint8)
 
 
@@ -204,8 +193,7 @@ def parity_check(x: jax.Array, par: jax.Array) -> jax.Array:
     """Number of 64-bit words whose parity mismatches (detected errors)."""
     p = pack_words(x)
     _, cnt = _parity.parity_check_words(p.lo, p.hi, par.astype(jnp.uint32),
-                                        block_rows=_bm(p.lo.shape[0]),
-                                        interpret=INTERPRET)
+                                        block_rows=_bm(p.lo.shape[0]))
     return jnp.sum(cnt)
 
 
@@ -213,8 +201,7 @@ def parity_error_words(x: jax.Array, par: jax.Array) -> jax.Array:
     """Per-word boolean error mask, shape (M, LANES)."""
     p = pack_words(x)
     err, _ = _parity.parity_check_words(p.lo, p.hi, par.astype(jnp.uint32),
-                                        block_rows=_bm(p.lo.shape[0]),
-                                        interpret=INTERPRET)
+                                        block_rows=_bm(p.lo.shape[0]))
     bits = (err[..., :, None] >> jnp.arange(8, dtype=jnp.uint32)) & 1
     return bits.reshape(p.lo.shape).astype(jnp.bool_)
 
@@ -230,16 +217,17 @@ def restore_words(x: jax.Array, good: jax.Array, word_mask: jax.Array
 
 
 # --------------------------------------------------------------- bitflip
+@jax.jit
 def inject_bitflips(x: jax.Array, word_idx: jax.Array, bit_idx: jax.Array
                     ) -> jax.Array:
     """Flip bits (word_idx[e], bit_idx[e]) of tensor ``x`` (packed space).
 
-    ``word_idx`` entries < 0 are inactive slots.
+    ``word_idx`` entries < 0 are inactive slots. One program, so the packed
+    copies of a large leaf are not all live at once.
     """
     p = pack_words(x)
     lo, hi = _bitflip.bitflip_words(p.lo, p.hi,
                                     word_idx.astype(jnp.int32),
                                     bit_idx.astype(jnp.int32),
-                                    block_rows=_bm(p.lo.shape[0]),
-                                    interpret=INTERPRET)
+                                    block_rows=_bm(p.lo.shape[0]))
     return unpack_words(Packed(lo, hi), x.shape, x.dtype)

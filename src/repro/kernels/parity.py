@@ -12,6 +12,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
+
 _POP = jax.lax.population_count
 
 
@@ -20,10 +22,20 @@ def _parity_bits(lo, hi):
 
 
 def _pack8(bits):
-    bm, w = bits.shape
-    grp = bits.reshape(bm, w // 8, 8).astype(jnp.uint32)
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (bm, w // 8, 8), 2)
-    return jnp.sum(grp << shifts, axis=-1)
+    """(BM, W) 0/1 words -> (BM, W//8) bytes, word ``8c + j`` in bit ``j``
+    of byte ``c``. Mosaic has no unsigned reduction and no lane-splitting
+    reshape, so the packing is one matmul against the constant (W, W//8)
+    matrix holding ``2**(r % 8)`` where ``r // 8 == c``. Every operand is
+    0/1 or a power of two <= 128 and every sum is <= 255, all exact in
+    bf16 and f32, so the result is exact at any matmul precision."""
+    w = bits.shape[1]
+    r = jax.lax.broadcasted_iota(jnp.int32, (w, w // 8), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (w, w // 8), 1)
+    weights = jnp.where(r // 8 == c, jnp.left_shift(1, r % 8),
+                        0).astype(jnp.float32)
+    packed = jnp.dot(bits.astype(jnp.int32).astype(jnp.float32), weights,
+                     preferred_element_type=jnp.float32)
+    return packed.astype(jnp.int32).astype(jnp.uint32)
 
 
 def _encode_kernel(lo_ref, hi_ref, par_ref):
@@ -44,7 +56,7 @@ def _row_spec(bm, w):
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def parity_encode_words(lo, hi, *, block_rows: int = 128,
-                        interpret: bool = True):
+                        interpret=None):
     """lo, hi: (M, W) uint32 -> packed parity (M, W//8) uint32."""
     m, w = lo.shape
     bm = min(block_rows, m)
@@ -55,13 +67,13 @@ def parity_encode_words(lo, hi, *, block_rows: int = 128,
         in_specs=[_row_spec(bm, w)] * 2,
         out_specs=_row_spec(bm, w // 8),
         out_shape=jax.ShapeDtypeStruct((m, w // 8), jnp.uint32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(lo, hi)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def parity_check_words(lo, hi, par, *, block_rows: int = 128,
-                       interpret: bool = True):
+                       interpret=None):
     """Returns (packed error bits (M, W//8), per-row error count (M,1))."""
     m, w = lo.shape
     bm = min(block_rows, m)
@@ -74,5 +86,5 @@ def parity_check_words(lo, hi, par, *, block_rows: int = 128,
         in_specs=[_row_spec(bm, w)] * 2 + [_row_spec(bm, w // 8)],
         out_specs=(_row_spec(bm, w // 8), _row_spec(bm, 1)),
         out_shape=outs,
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(lo, hi, par)

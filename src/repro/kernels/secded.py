@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import hsiao
+from repro.kernels import hsiao, interpret_mode
 
 _POP = jax.lax.population_count
 
@@ -79,7 +79,7 @@ def _row_spec(bm: int, w: int):
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def secded_encode_words(lo, hi, *, block_rows: int = 128,
-                        interpret: bool = True):
+                        interpret=None):
     """lo, hi: (M, W) uint32 -> ecc (M, W) uint32. M % block_rows == 0."""
     m, w = lo.shape
     bm = min(block_rows, m)
@@ -90,13 +90,13 @@ def secded_encode_words(lo, hi, *, block_rows: int = 128,
         in_specs=[_row_spec(bm, w)] * 2,
         out_specs=_row_spec(bm, w),
         out_shape=jax.ShapeDtypeStruct((m, w), jnp.uint32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(lo, hi)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def secded_scrub_words(lo, hi, ecc, *, block_rows: int = 128,
-                       interpret: bool = True):
+                       interpret=None):
     """Scrub/correct. Returns (lo', hi', ecc', corr (M,1), unc (M,1))."""
     m, w = lo.shape
     bm = min(block_rows, m)
@@ -114,5 +114,5 @@ def secded_scrub_words(lo, hi, ecc, *, block_rows: int = 128,
         in_specs=[_row_spec(bm, w)] * 3,
         out_specs=(_row_spec(bm, w),) * 3 + (_row_spec(bm, 1),) * 2,
         out_shape=outs,
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(lo, hi, ecc)

@@ -92,7 +92,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool,
         jitted = jax.jit(step, in_shardings=(state_sh, b_sh),
                          out_shardings=(state_sh, None),
                          donate_argnums=(0,))
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(state_shape, batch_shape)
     elif shape.kind == "prefill":
         params_shape = S.params_shape(cfg)
@@ -109,7 +109,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool,
                                                  seq_shard_cache)
         jitted = jax.jit(step, in_shardings=(p_sh, b_sh),
                          out_shardings=(None, out_cache_sh))
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(params_shape, batch_shape)
     else:  # decode
         params_shape = S.params_shape(cfg)
@@ -134,7 +134,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool,
                          out_shardings=(c_sh, tok_sh,
                                         rules.replicated(mesh)),
                          donate_argnums=(1,))
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(params_shape, cache_shape, tok_s, pos_s)
 
     rec["lower_s"] = round(time.time() - t0, 2)

@@ -24,7 +24,7 @@ from repro.core import DESIGN_POINTS, Tier
 
 def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="llama3-8b")
+    ap.add_argument("--arch", default="granite-moe-3b-a800m")
     ap.add_argument("--tiny", action=argparse.BooleanOptionalAction,
                     default=True)
     # traffic
@@ -63,7 +63,9 @@ def build_parser() -> argparse.ArgumentParser:
                     help="override the policy's params scrub cadence "
                          "(iterations)")
     # harness
-    ap.add_argument("--clock", choices=("model", "wall"), default="model")
+    ap.add_argument("--clock", choices=("model", "wall"), default="wall",
+                    help="wall: measured step times (the served clock); "
+                         "model: the deterministic virtual service model")
     ap.add_argument("--golden", action="store_true",
                     help="also run a zero-injection golden pass on the same "
                          "trace and report the incorrect-response rate")
@@ -105,8 +107,11 @@ def main(argv=None):
         return 0
 
     import jax
+    from repro.launch.workdir import enable_compile_cache
     from repro.models import init_params
     from repro.serve import OnlineEngine, incorrect_rate
+
+    enable_compile_cache()
 
     params = init_params(jax.random.PRNGKey(0), cfg)
 

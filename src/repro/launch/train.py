@@ -18,10 +18,12 @@ from repro.configs import get_config, get_tiny
 from repro.configs.base import TrainConfig
 from repro.core import DESIGN_POINTS
 from repro.data.synthetic import batch_stream
+from repro.launch.workdir import CKPT_ROOT, enable_compile_cache
 from repro.runtime.train_loop import LoopConfig, run_training
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="lm-100m")
     ap.add_argument("--tiny", action="store_true")
@@ -34,7 +36,7 @@ def main():
     ap.add_argument("--scrub-interval", type=int, default=20)
     ap.add_argument("--error-rate", type=float, default=0.0)
     ap.add_argument("--fail-at", type=int, nargs="*", default=[])
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
+    ap.add_argument("--ckpt-dir", default=str(CKPT_ROOT / "repro_ckpt"))
     ap.add_argument("--ckpt-interval", type=int, default=25)
     ap.add_argument("--grad-compress", action="store_true")
     args = ap.parse_args()

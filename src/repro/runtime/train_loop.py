@@ -34,6 +34,7 @@ from repro.checkpoint.store import CheckpointStore
 from repro.configs.base import ModelConfig, TrainConfig
 from repro.core import (HRMPolicy, MemoryDomain, Response, RestartRequired,
                         RetirementMap)
+from repro.launch.workdir import CKPT_ROOT
 from repro.runtime.steps import init_train_state, make_train_step
 
 
@@ -41,7 +42,7 @@ from repro.runtime.steps import init_train_state, make_train_step
 class LoopConfig:
     steps: int = 100
     ckpt_interval: int = 50
-    ckpt_dir: str = "/tmp/repro_ckpt"
+    ckpt_dir: str = str(CKPT_ROOT / "repro_ckpt")
     seed: int = 0
     # fault simulation
     error_rate_per_step: float = 0.0        # expected injected errors/step

@@ -17,7 +17,7 @@ where that costs us, e.g. granite's 40 experts on a 16-way model axis).
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Sequence, Tuple
+from typing import Any, Dict, Sequence, Tuple
 
 import jax
 import numpy as np
@@ -209,13 +209,11 @@ def replicated(mesh: Mesh):
 
 
 # ------------------------------------------------- activation hints
-def ambient_mesh() -> Optional[Mesh]:
-    try:
-        from jax._src import mesh as mesh_lib
-        m = mesh_lib.thread_resources.env.physical_mesh
-        return None if m.empty else m
-    except Exception:   # pragma: no cover — private-API guard
-        return None
+def ambient_mesh():
+    """The mesh entered with ``jax.set_mesh`` (an ``AbstractMesh``), or
+    None outside one."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def hint(x, *axes):

@@ -97,7 +97,7 @@ CODEC_IDS = sorted(CODECS)
 
 
 def _kw(rows):
-    return dict(block_rows=rows, interpret=ops.INTERPRET)
+    return dict(block_rows=rows)
 
 
 def _payload(rows, width, seed):

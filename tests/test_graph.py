@@ -9,11 +9,16 @@ from repro.core import MemoryDomain, Tier, detect_recover, detect_recover_l
 from repro.core.errormodel import InjectionPlan
 from repro.graph import (bfs, bfs_reference, graph_state, n_padded,
                          pagerank, powerlaw_graph, top_k)
-from repro.kernels import ops
 from repro.kernels.segsum import (edge_segment_push,
                                   edge_segment_push_oracle,
                                   edge_segment_push_ref, frontier_update,
                                   frontier_update_oracle, pad_edges)
+
+# The push kernels and their oracles run the same f32 tile math, but the
+# kernel's dot and the eager oracle's accumulate in different orders, so
+# they agree to a few ulp, not bit for bit (2 ulp measured; 1e-6 is ~8).
+# Index, frontier and ECC paths stay exact.
+ORACLE_RTOL = 1e-6
 
 
 @pytest.fixture(scope="module")
@@ -60,8 +65,9 @@ def test_spmv_bit_equal_oracle():
     dst = jnp.asarray(rng.integers(0, n, e))
     x = jnp.asarray(rng.random((1, n)), jnp.float32)
     s, d = pad_edges(src, dst, n)
-    y = edge_segment_push(s, d, x, interpret=ops.INTERPRET)
-    assert bool(jnp.all(y == edge_segment_push_oracle(s, d, x)))
+    y = edge_segment_push(s, d, x)
+    np.testing.assert_allclose(y, edge_segment_push_oracle(s, d, x),
+                               rtol=ORACLE_RTOL, atol=0)
 
 
 def test_spmv_allclose_segment_sum():
@@ -71,7 +77,7 @@ def test_spmv_allclose_segment_sum():
     dst = jnp.asarray(rng.integers(0, n, e))
     x = jnp.asarray(rng.random((1, n)), jnp.float32)
     s, d = pad_edges(src, dst, n)
-    y = edge_segment_push(s, d, x, interpret=ops.INTERPRET)
+    y = edge_segment_push(s, d, x)
     assert jnp.allclose(y, edge_segment_push_ref(s, d, x),
                         rtol=1e-5, atol=1e-6)
 
@@ -84,7 +90,7 @@ def test_spmv_corrupted_indices_drop_edges_in_all_backends():
     dst = jnp.asarray([2, -7, 2, 2], jnp.int32)
     x = 10.0 * jnp.ones((1, n), jnp.float32)
     s, d = pad_edges(src, dst, n)
-    y = edge_segment_push(s, d, x, interpret=ops.INTERPRET)
+    y = edge_segment_push(s, d, x)
     assert float(y.sum()) == 10.0          # only edge (3 -> 2) survives
     assert bool(jnp.all(y == edge_segment_push_oracle(s, d, x)))
     assert bool(jnp.all(y == edge_segment_push_ref(s, d, x)))
@@ -96,7 +102,7 @@ def test_spmv_sentinel_padding_inert():
     dst = jnp.asarray([2, 2], jnp.int32)
     x = jnp.ones((1, n), jnp.float32)
     s, d = pad_edges(src, dst, n)          # pads with sentinel n
-    y = edge_segment_push(s, d, x, interpret=ops.INTERPRET)
+    y = edge_segment_push(s, d, x)
     assert float(y[0, 2]) == 2.0
     assert float(y.sum()) == 2.0           # padded slots contribute nothing
 
@@ -119,7 +125,7 @@ def test_frontier_kernel_bit_equal():
     pushed = jnp.asarray(rng.random((1, n)) > 0.7, jnp.float32)
     visited = jnp.asarray(rng.integers(0, 2, (1, n)), jnp.int32)
     dist = jnp.where(visited > 0, 1, -1).astype(jnp.int32)
-    got = frontier_update(pushed, visited, dist, 2, interpret=ops.INTERPRET)
+    got = frontier_update(pushed, visited, dist, 2)
     want = frontier_update_oracle(pushed, visited, dist, 2)
     for a, b in zip(got, want):
         assert bool(jnp.all(a == b))
@@ -130,7 +136,8 @@ def test_pagerank_backends_agree(graph, state):
     _, r_pallas, _ = pagerank(state, graph.n, iters=10, backend="pallas")
     _, r_oracle, _ = pagerank(state, graph.n, iters=10, backend="oracle")
     _, r_ref, _ = pagerank(state, graph.n, iters=10, backend="segment_sum")
-    assert bool(jnp.all(r_pallas == r_oracle))      # bit-equivalence
+    np.testing.assert_allclose(r_pallas, r_oracle, rtol=ORACLE_RTOL,
+                               atol=0)
     assert jnp.allclose(r_pallas, r_ref, rtol=1e-5, atol=1e-7)
 
 

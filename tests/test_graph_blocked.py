@@ -23,6 +23,10 @@ from repro.kernels.segsum import (EDGE_TILE, NODE_LANES,
                                   edge_segment_push_blocked_ref,
                                   fit_edge_tile)
 
+# Kernel vs oracle: the same f32 tile math accumulated in different orders
+# agrees to a few ulp (2 measured; 1e-6 is ~8), not bit for bit.
+ORACLE_RTOL = 1e-6
+
 
 @pytest.fixture(scope="module")
 def graph():
@@ -102,15 +106,15 @@ def test_node_block_marker(graph, blocked_state):
        te=st.sampled_from((128, 256)),
        corrupt=st.booleans())
 def test_blocked_push_matches_oracle_and_ref(seed, n, e, bni, te, corrupt):
-    """Property: the blocked Pallas kernel is bit-identical to its jnp
-    oracle and allclose to the blocked segment_sum ref over random
+    """Property: the blocked Pallas kernel matches its jnp oracle to a few
+    ulp and is allclose to the blocked segment_sum ref over random
     bucketed graphs — with and without post-bucketing corruption of edge
     ids and dispatch tables (drop/reroute semantics)."""
     args = _random_blocked(seed, n, e, bni, te, corrupt=corrupt)
     y = edge_segment_push_blocked(*args, node_block=bni)
     yo = edge_segment_push_blocked_oracle(*args, node_block=bni)
     yr = edge_segment_push_blocked_ref(*args, node_block=bni)
-    assert bool(jnp.all(y == yo))
+    np.testing.assert_allclose(y, yo, rtol=ORACLE_RTOL, atol=0)
     assert jnp.allclose(y, yr, rtol=1e-5, atol=1e-6)
 
 
@@ -147,7 +151,7 @@ def test_blocked_pagerank_backends_agree(graph, blocked_state):
     _, ro, _ = pagerank(blocked_state, graph.n, iters=8, backend="oracle")
     _, rr, _ = pagerank(blocked_state, graph.n, iters=8,
                         backend="segment_sum")
-    assert bool(jnp.all(rp == ro))               # bit-equivalence
+    np.testing.assert_allclose(rp, ro, rtol=ORACLE_RTOL, atol=0)
     assert jnp.allclose(rp, rr, rtol=1e-5, atol=1e-7)
 
 

@@ -37,6 +37,49 @@ def test_pack_roundtrip(shape, dtype):
     assert (np.asarray(x2) == np.asarray(x)).all()
 
 
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("shape", [(129,), (37, 53), (512, 300)])
+def test_pack_layout_is_flat_bytes(shape, dtype):
+    """Word w holds the tensor's flat bytes 8w..8w+7, little-endian: lo the
+    first four, hi the last four, zero-padded to whole (M, LANES) rows."""
+    x = _mk(shape, dtype)
+    raw = np.asarray(x).tobytes()
+    p = ops.pack_words(x)
+    words = np.zeros(p.lo.size * 2, np.uint32)
+    words.view(np.uint8)[:len(raw)] = np.frombuffer(raw, np.uint8)
+    words = words.reshape(-1, 2)
+    assert np.array_equal(np.asarray(p.lo).reshape(-1), words[:, 0])
+    assert np.array_equal(np.asarray(p.hi).reshape(-1), words[:, 1])
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float16])
+@pytest.mark.parametrize("shape", [(3001,), (3, 5, 7, 64)])
+def test_pack_keeps_nan_payloads_and_subnormals(shape, dtype):
+    """Any bits pack to the flat-byte layout and unpack unchanged, NaN
+    payloads and subnormals included: the integer view comes before any
+    other op (on CPU; a TPU's own bitcast rewrites those patterns)."""
+    raw = np.random.default_rng(1).integers(0, 1 << 16, shape,
+                                            dtype=np.uint16)
+    x = jax.device_put(raw.view(jnp.dtype(dtype)))
+    p = ops.pack_words(x)
+    words = np.zeros(p.lo.size * 2, np.uint32)
+    words.view(np.uint16)[:raw.size] = raw.reshape(-1)
+    assert np.array_equal(np.asarray(p.lo).reshape(-1), words[0::2])
+    assert np.array_equal(np.asarray(p.hi).reshape(-1), words[1::2])
+    back = ops.unpack_words(p, shape, dtype)
+    assert np.array_equal(np.asarray(back).view(np.uint16), raw)
+
+
+def test_interpret_mode_follows_backend(monkeypatch):
+    from repro.kernels import interpret_mode
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert interpret_mode() is True and interpret_mode(False) is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert interpret_mode() is False
+    with pytest.raises(ValueError):
+        interpret_mode(True)
+
+
 # tensor-level wrappers over each codec: one smoke round-trip per tier
 # (the words-level kernels themselves are proven in ecc_conformance.py)
 @pytest.mark.parametrize("encode,scrub", [

@@ -94,6 +94,18 @@ def _classify(path) -> str:
     return classify_path(path, "params")
 
 
+def _root_kind(path: str) -> str:
+    """Root kind of a leaf path string, as ``_classify`` reads it."""
+    root, sep, _ = path.partition("/")
+    return _ROOT_KIND.get(root.lower(), "params") if sep else "params"
+
+
+def _jit_named(fn: Callable, name: str) -> Callable:
+    """``jax.jit(fn)`` lowered as module ``jit_<name>``."""
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn)
+
+
 def _supported(leaf) -> bool:
     if not hasattr(leaf, "dtype") or not hasattr(leaf, "shape"):
         return False
@@ -107,7 +119,7 @@ class DomainSpec:
     compare by it) and key the jit caches for scrub/encode programs.
     """
     __slots__ = ("policy", "leaves", "treedef", "groups", "by_path",
-                 "protectable", "_byte_weights", "_hash")
+                 "protectable", "kind", "_byte_weights", "_hash")
 
     def __init__(self, policy: HRMPolicy, leaves: Tuple[LeafSpec, ...],
                  treedef):
@@ -123,6 +135,10 @@ class DomainSpec:
             for t, ls in grouped.items()}
         self.by_path = {s.path: s for s in leaves}
         self.protectable = tuple(s for s in leaves if s.rows > 0)
+        # root kind of the payload (``domain`` for a mixed one): it names
+        # the compiled programs, so a device trace tells them apart
+        kinds = {_root_kind(s.path) for s in leaves}
+        self.kind = kinds.pop() if len(kinds) == 1 else "domain"
         w = np.array([s.nbytes for s in self.protectable], dtype=np.float64)
         self._byte_weights = w / w.sum() if w.size and w.sum() > 0 else w
         self._hash = hash((policy, leaves, treedef))
@@ -300,7 +316,7 @@ def _compiled_scrub(spec: DomainSpec, key: Optional[Tuple[str, ...]]
                 off += s.rows
         return mod, new_sc, corr, unc
 
-    return jax.jit(fn)
+    return _jit_named(fn, f"{spec.kind}_scrub")
 
 
 @functools.lru_cache(maxsize=None)
@@ -381,7 +397,7 @@ def _compiled_scrub_rows(spec: DomainSpec, key: Optional[Tuple[str, ...]],
                 o += b - a
         return mod, new_sc, corr, unc
 
-    return jax.jit(fn)
+    return _jit_named(fn, f"{spec.kind}_scrub_slice")
 
 
 @functools.lru_cache(maxsize=None)
@@ -424,7 +440,7 @@ def _compiled_encode(spec: DomainSpec, key: Optional[Tuple[str, ...]]
                     tier, leaves, selected[tier], padded,
                     _block_rows(padded))
             return sc
-        return jax.jit(fn_full)
+        return _jit_named(fn_full, f"{spec.kind}_encode")
 
     def fn_partial(leaves, sidecar):
         new_sc = {k: dict(v) for k, v in sidecar.items()}
@@ -439,7 +455,7 @@ def _compiled_encode(spec: DomainSpec, key: Optional[Tuple[str, ...]]
                     sidecar[tier.value][name], sel, new[:total])
         return new_sc
 
-    return jax.jit(fn_partial)
+    return _jit_named(fn_partial, f"{spec.kind}_encode_rows")
 
 
 # =====================================================================

@@ -45,6 +45,7 @@ from typing import Iterable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.graph.generate import node_block_of
 from repro.kernels.segsum import (NODE_LANES, edge_segment_push,
@@ -172,6 +173,8 @@ def pagerank_scrubbed(domain, n: int, *, iters: int = 20,
     only ~1/scrub_slices of a monolithic pass added per iteration.
 
     ``domain`` must protect a ``{"graph": graph_state(...)}`` payload.
+    Each iteration is a ``graph.iteration`` span holding ``graph.step``,
+    ``graph.rank_encode`` and ``graph.scrub_slice``.
     Returns (domain, rank (1, n_pad), L1 delta, merged ScrubReport).
     """
     from repro.core.sidecar import ScrubReport
@@ -180,17 +183,21 @@ def pagerank_scrubbed(domain, n: int, *, iters: int = 20,
     uncorrectable: dict = {}
     prev = domain.payload["graph"]["rank"]["rank"]
     for it in range(iters):
-        prev = domain.payload["graph"]["rank"]["rank"]
-        state = pagerank_step(domain.payload["graph"], n, damping=damping,
-                              backend=backend)
-        domain = domain.refresh({**domain.payload, "graph": state},
-                                paths=["graph/rank/rank"])
-        domain, rep = domain.scrub_partial(it, slices=scrub_slices,
-                                           paths=paths)
-        for k, v in rep.corrected.items():
-            corrected[k] = corrected.get(k, 0) + v
-        for k, v in rep.detected_uncorrectable.items():
-            uncorrectable[k] = uncorrectable.get(k, 0) + v
+        with TraceAnnotation("graph.iteration", it=it):
+            prev = domain.payload["graph"]["rank"]["rank"]
+            with TraceAnnotation("graph.step"):
+                state = pagerank_step(domain.payload["graph"], n,
+                                      damping=damping, backend=backend)
+            with TraceAnnotation("graph.rank_encode"):
+                domain = domain.refresh({**domain.payload, "graph": state},
+                                        paths=["graph/rank/rank"])
+            with TraceAnnotation("graph.scrub_slice"):
+                domain, rep = domain.scrub_partial(it, slices=scrub_slices,
+                                                   paths=paths)
+            for k, v in rep.corrected.items():
+                corrected[k] = corrected.get(k, 0) + v
+            for k, v in rep.detected_uncorrectable.items():
+                uncorrectable[k] = uncorrectable.get(k, 0) + v
     rank = domain.payload["graph"]["rank"]["rank"]
     delta = jnp.sum(jnp.abs(rank - prev))
     return domain, rank, delta, ScrubReport(

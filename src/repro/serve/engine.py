@@ -26,11 +26,19 @@ bit-identical to ``runtime.serve_loop.serve_batch``
 (``tests/test_serve_plane.py`` pins this).
 
 Time: the engine advances a virtual clock by a calibrated service model
-(``--clock model``, deterministic — the CI/test path) or by measured wall
-time per step (``--clock wall``). An error storm compresses one
+(``--clock model``, deterministic — the CI/test path) or reads the wall
+time since ``run`` began at the prefill's and the decode's host syncs
+(``--clock wall``), so the KV check, the KV refresh and the params scrub
+count in every token's stamp. An error storm compresses one
 server-month's error budget (default 540 incident errors) into the run;
 availability is computed from *measured* recovery/crash events against
 that month (docs/DESIGN.md §9).
+
+Tracing: each iteration is a ``serve.iteration`` step span holding one
+span per phase (``serve.kv_check``, ``serve.params_scrub``,
+``serve.prefill``, ``serve.decode``, ``serve.kv_refresh``,
+``serve.inject``), and the device programs lower as ``jit_serve_decode``
+and ``jit_serve_prefill``; both cost nothing without a profiler.
 """
 from __future__ import annotations
 
@@ -44,6 +52,7 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.core import HRMPolicy, MemoryDomain, Response, Tier
@@ -106,7 +115,7 @@ def _make_paged_decode(cfg: ModelConfig, page_size: int):
     dh, H = cfg.head_dim, cfg.n_heads
     cdt = dtype_of(cfg.compute_dtype)
 
-    def step(params, pool_k, pool_v, table, tokens, pos):
+    def serve_decode(params, pool_k, pool_v, table, tokens, pos):
         S, P = table.shape
         smax = P * page_size
         x = params["embed"][tokens][:, None, :].astype(cdt)    # (S,1,D)
@@ -162,7 +171,7 @@ def _make_paged_decode(cfg: ModelConfig, page_size: int):
         ok = jnp.isfinite(logits).all()
         return pk, pv, nxt, ok
 
-    return step
+    return serve_decode
 
 
 def _make_prefill_write(cfg: ModelConfig, page_size: int):
@@ -173,7 +182,7 @@ def _make_prefill_write(cfg: ModelConfig, page_size: int):
       -> (pool_k', pool_v', first_token, ok)
     """
 
-    def fn(params, pool_k, pool_v, tokens, true_len, pages):
+    def serve_prefill(params, pool_k, pool_v, tokens, true_len, pages):
         logits, _, cache = forward(params, {"tokens": tokens}, cfg,
                                    return_cache=True)
         last = jax.lax.dynamic_index_in_dim(logits[0], true_len - 1,
@@ -193,7 +202,7 @@ def _make_prefill_write(cfg: ModelConfig, page_size: int):
         pool_v = pool_v.at[:, pages].set(v)
         return pool_k, pool_v, first, jnp.isfinite(last).all()
 
-    return fn
+    return serve_prefill
 
 
 @functools.lru_cache(maxsize=None)
@@ -239,6 +248,7 @@ class OnlineEngine:
         self.peer_recovery = peer_recovery
         self._kv_peer: Optional[Dict[str, jax.Array]] = None
         self.clock_mode = clock
+        self._origin = 0.0               # wall clock: when run() began
         self.service = service or ServiceModel()
         self.max_prefills_per_step = max_prefills_per_step
         self.max_queue = max_queue
@@ -282,9 +292,13 @@ class OnlineEngine:
         return {"kv_cache": {"k": self.cache.pool_k,
                              "v": self.cache.pool_v}}
 
-    def _advance(self, now: float, model_cost: float, t_wall: float
-                 ) -> float:
-        return now + (t_wall if self.clock_mode == "wall" else model_cost)
+    def _advance(self, now: float, model_cost: float) -> float:
+        """The served clock at a host sync: the wall time since ``run``
+        began, protection passes included (idle jumps move the origin
+        back), or the virtual clock advanced by the service model."""
+        if self.clock_mode == "wall":
+            return time.perf_counter() - self._origin
+        return now + model_cost
 
     def describe(self) -> str:
         ps = self.param_domain.stats()
@@ -298,22 +312,22 @@ class OnlineEngine:
 
     # ------------------------------------------------------------ prefill
     def _run_prefill(self, req: Request, pages: np.ndarray
-                     ) -> Tuple[int, bool, float]:
+                     ) -> Tuple[int, bool]:
         # only prompt pages are written at prefill; decode fills the rest
         n_pp = -(-req.prompt_len // self._page_size)
         sb = n_pp * self._page_size
         tokens = np.zeros((1, sb), np.int32)
         tokens[0, :req.prompt_len] = req.prompt
-        t0 = time.perf_counter()
-        pk, pv, first, ok = self._prefill(
-            self._params(), self.cache.pool_k, self.cache.pool_v,
-            jnp.asarray(tokens), jnp.int32(req.prompt_len),
-            jnp.asarray(pages[:n_pp]))
-        first = int(first)
-        ok = bool(ok)
-        t_wall = time.perf_counter() - t0
+        with TraceAnnotation("serve.prefill", rid=req.rid,
+                             prompt_len=req.prompt_len, pages=n_pp):
+            pk, pv, first, ok = self._prefill(
+                self._params(), self.cache.pool_k, self.cache.pool_v,
+                jnp.asarray(tokens), jnp.int32(req.prompt_len),
+                jnp.asarray(pages[:n_pp]))
+            first = int(first)
+            ok = bool(ok)
         self.cache.adopt_pools(pk, pv)
-        return first, ok, t_wall
+        return first, ok
 
     # -------------------------------------------------------- fault plane
     def _inject_one(self, counters: SLOCounters) -> None:
@@ -365,6 +379,7 @@ class OnlineEngine:
 
     def _scrub_kv(self, counters: SLOCounters) -> None:
         self.kv_domain, rep = self.kv_domain.scrub()
+        counters.kv_pages_checked += self.cache.n_pages
         c, u = rep.totals()
         counters.kv_corrected += c
         counters.kv_detected += u
@@ -406,7 +421,104 @@ class OnlineEngine:
                                jnp.zeros_like(self.cache.pool_v))
         self.kv_domain = MemoryDomain.protect(self._kv_state(),
                                               kv_policy(self.kv_tier))
+        if self.kv_tier is not Tier.NONE:
+            counters.kv_pages_encoded += self.cache.n_pages
         self._kv_peer = None             # stale after the restart
+
+    # ---------------------------------------------------------- iteration
+    def _iteration(self, router: RequestRouter, counters: SLOCounters,
+                   storm: deque, now: float, it: int) -> float:
+        """One engine iteration; returns the served clock after it."""
+        n_pages = self.cache.n_pages
+        # 1. access-path KV check: catches strikes injected after the
+        #    previous refresh, before any re-encode can launder them
+        if self.kv_tier is not Tier.NONE:
+            with TraceAnnotation("serve.kv_check", pages=n_pages):
+                self._scrub_kv(counters)
+        # 2. params patrol scrub on the policy cadence
+        if (self.params_policy is not None and self.scrub_every > 0
+                and it > 0 and it % self.scrub_every == 0):
+            with TraceAnnotation("serve.params_scrub"):
+                self._scrub_params(counters)
+        # 3. route arrivals, admit prefills into free slots
+        router.poll(now)
+        admitted = 0
+        while admitted < self.max_prefills_per_step:
+            req = router.peek()
+            if req is None:
+                break
+            if self.cache.pages_needed(req.footprint_tokens()) > \
+                    self.cache.max_pages_per_slot:
+                router.take()            # can never fit: shed it
+                router.shed.append(req)
+                continue
+            if not self.sched.can_admit(req):
+                break
+            router.take()
+            slot = self.sched.free_slot()
+            pages = self.cache.alloc(slot, req.footprint_tokens())
+            first, ok = self._run_prefill(req, pages)
+            counters.prefills += 1
+            counters.kv_pages_written += self.cache.pages_needed(
+                req.prompt_len)
+            now = self._advance(now, self.service.prefill_cost(
+                req.prompt_len))
+            if not ok:
+                self.cache.release(slot)
+                router.requeue(req)
+                self._crash_reset(router, counters)
+                break
+            self.sched.admit(req, first, now)
+            admitted += 1
+        # 4. one continuous-batching decode step over every slot
+        if self.sched.n_active:
+            tokens, pos = self.sched.batch_inputs()
+            active = self.sched.n_active
+            with TraceAnnotation("serve.decode", active=active):
+                pk, pv, nxt, ok = self._decode(
+                    self._params(), self.cache.pool_k, self.cache.pool_v,
+                    self.cache.device_table(), jnp.asarray(tokens),
+                    jnp.asarray(pos))
+                nxt = np.asarray(nxt)
+                ok = bool(ok)
+            self.cache.adopt_pools(pk, pv)
+            counters.decode_steps += 1
+            counters.kv_pages_written += active    # one page per slot
+            now = self._advance(now, self.service.decode_cost(active))
+            if ok:
+                self.sched.record_step(nxt, now)
+            else:
+                self._crash_reset(router, counters)
+        elif not router.queue:
+            nxt_t = router.next_arrival()
+            if nxt_t is not None and nxt_t > now:
+                self._origin -= nxt_t - now  # idle: jump to next arrival
+                now = nxt_t
+        # 5. write-path ECC: re-encode the KV sidecar over this
+        #    step's legitimate writes
+        if self.kv_tier is not Tier.NONE:
+            with TraceAnnotation("serve.kv_refresh", pages=n_pages):
+                self.kv_domain = self.kv_domain.refresh(self._kv_state())
+            counters.kv_pages_encoded += n_pages
+        else:
+            self.kv_domain = self.kv_domain.adopt(self._kv_state())
+        if self.peer_recovery:
+            # peer image: a replica that doesn't take this storm's
+            # strikes holds exactly this post-write pool state
+            self._kv_peer = {"kv_cache/k": self.cache.pool_k,
+                             "kv_cache/v": self.cache.pool_v}
+        # 6. the storm: fire every error due by the current clock
+        if storm and storm[0][0] <= now:
+            with TraceAnnotation("serve.inject"):
+                while storm and storm[0][0] <= now:
+                    _, strike = storm.popleft()
+                    if strike is None:
+                        self._inject_one(counters)
+                    else:
+                        self._inject_bound(strike, counters)
+        if self.debug_invariants:
+            self.cache.check_invariants()
+        return now
 
     # ---------------------------------------------------------------- run
     def run(self, trace: List[Request], *, storm_errors: int = 0,
@@ -435,91 +547,14 @@ class OnlineEngine:
             storm = deque((t, None) for t in np.sort(
                 self.rng.uniform(0.0, span, storm_errors)))
         now = 0.0
+        self._origin = time.perf_counter()
         it = 0
         while not (router.drained and self.sched.n_active == 0):
             if it >= max_iters:
                 raise RuntimeError(f"engine wedged after {max_iters} "
                                    f"iterations")
-            # 1. access-path KV check: catches strikes injected after the
-            #    previous refresh, before any re-encode can launder them
-            if self.kv_tier is not Tier.NONE:
-                self._scrub_kv(counters)
-            # 2. params patrol scrub on the policy cadence
-            if (self.params_policy is not None and self.scrub_every > 0
-                    and it > 0 and it % self.scrub_every == 0):
-                self._scrub_params(counters)
-            # 3. route arrivals, admit prefills into free slots
-            router.poll(now)
-            admitted = 0
-            while admitted < self.max_prefills_per_step:
-                req = router.peek()
-                if req is None:
-                    break
-                if self.cache.pages_needed(req.footprint_tokens()) > \
-                        self.cache.max_pages_per_slot:
-                    router.take()            # can never fit: shed it
-                    router.shed.append(req)
-                    continue
-                if not self.sched.can_admit(req):
-                    break
-                router.take()
-                slot = self.sched.free_slot()
-                pages = self.cache.alloc(slot, req.footprint_tokens())
-                first, ok, t_wall = self._run_prefill(req, pages)
-                counters.prefills += 1
-                now = self._advance(
-                    now, self.service.prefill_cost(req.prompt_len), t_wall)
-                if not ok:
-                    self.cache.release(slot)
-                    router.requeue(req)
-                    self._crash_reset(router, counters)
-                    break
-                self.sched.admit(req, first, now)
-                admitted += 1
-            # 4. one continuous-batching decode step over every slot
-            if self.sched.n_active:
-                tokens, pos = self.sched.batch_inputs()
-                t0 = time.perf_counter()
-                pk, pv, nxt, ok = self._decode(
-                    self._params(), self.cache.pool_k, self.cache.pool_v,
-                    self.cache.device_table(), jnp.asarray(tokens),
-                    jnp.asarray(pos))
-                nxt = np.asarray(nxt)
-                ok = bool(ok)
-                t_wall = time.perf_counter() - t0
-                self.cache.adopt_pools(pk, pv)
-                counters.decode_steps += 1
-                now = self._advance(
-                    now, self.service.decode_cost(self.sched.n_active),
-                    t_wall)
-                if ok:
-                    self.sched.record_step(nxt, now)
-                else:
-                    self._crash_reset(router, counters)
-            elif not router.queue:
-                nxt_t = router.next_arrival()
-                if nxt_t is not None:
-                    now = max(now, nxt_t)    # idle: jump to next arrival
-            # 5. write-path ECC: re-encode the KV sidecar over this
-            #    step's legitimate writes
-            if self.kv_tier is not Tier.NONE:
-                self.kv_domain = self.kv_domain.refresh(self._kv_state())
-            else:
-                self.kv_domain = self.kv_domain.adopt(self._kv_state())
-            if self.peer_recovery:
-                # peer image: a replica that doesn't take this storm's
-                # strikes holds exactly this post-write pool state
-                self._kv_peer = {"kv_cache/k": self.cache.pool_k,
-                                 "kv_cache/v": self.cache.pool_v}
-            # 6. the storm: fire every error due by the current clock
-            while storm and storm[0][0] <= now:
-                _, strike = storm.popleft()
-                if strike is None:
-                    self._inject_one(counters)
-                else:
-                    self._inject_bound(strike, counters)
-            if self.debug_invariants:
-                self.cache.check_invariants()
+            with StepTraceAnnotation("serve.iteration", step_num=it):
+                now = self._iteration(router, counters, storm, now, it)
             it += 1
         # drain the storm tail + one final scrub so every injected error
         # is detected/recovered and accounted before availability is read

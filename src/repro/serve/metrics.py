@@ -37,6 +37,12 @@ class SLOCounters:
     params_detected: int = 0
     kv_corrected: int = 0
     kv_detected: int = 0
+    # KV pages coded by the protection passes, against those the model
+    # wrote: each check and refresh covers the whole pool; a prefill
+    # writes its prompt pages, a decode step one page per active slot
+    kv_pages_checked: int = 0
+    kv_pages_encoded: int = 0
+    kv_pages_written: int = 0
     recovery_events: int = 0
     peer_recovery_events: int = 0
     crash_events: int = 0

@@ -15,7 +15,7 @@ a single ``"params"`` root. ``MemoryDomain`` replaces that wiring with one
 
 and a verb API: ``MemoryDomain.protect(state, policy)``, ``.scrub(step)``,
 ``.recover(report, ...)``, ``.inject(rng, n, hard=)``, ``.refresh(state,
-paths=)``, ``.stats()``.
+paths=)``, ``.refresh_pages(pages, state)``, ``.stats()``.
 
 Execution model — tier-grouped batching: instead of the legacy per-leaf
 Python loop (one Pallas dispatch per leaf plus an O(n_leaves^2)
@@ -100,10 +100,10 @@ def _root_kind(path: str) -> str:
     return _ROOT_KIND.get(root.lower(), "params") if sep else "params"
 
 
-def _jit_named(fn: Callable, name: str) -> Callable:
-    """``jax.jit(fn)`` lowered as module ``jit_<name>``."""
+def _jit_named(fn: Callable, name: str, **jit_kw) -> Callable:
+    """``jax.jit(fn, **jit_kw)`` lowered as module ``jit_<name>``."""
     fn.__name__ = fn.__qualname__ = name
-    return jax.jit(fn)
+    return jax.jit(fn, **jit_kw)
 
 
 def _supported(leaf) -> bool:
@@ -174,6 +174,12 @@ class DomainSpec:
             if sel:
                 out[t] = sel
         return out
+
+    def slices_aligned(self) -> bool:
+        """Whether every protected leaf's slices along its second axis are
+        whole packed rows, so ``refresh_pages`` can encode them alone."""
+        return all(_slice_rows(s) for ls in self.select(None).values()
+                   for s in ls)
 
 
 # =====================================================================
@@ -400,6 +406,27 @@ def _compiled_scrub_rows(spec: DomainSpec, key: Optional[Tuple[str, ...]],
     return _jit_named(fn, f"{spec.kind}_scrub_slice")
 
 
+def _encode_tier(tier: Tier, lo, hi, bm: int) -> Dict[str, jax.Array]:
+    """One tier's fresh sidecar buffers for a packed (rows, LANES) window."""
+    if tier is Tier.SECDED:
+        return {"ecc": secded_encode_words(
+            lo, hi, block_rows=bm).astype(jnp.uint8)}
+    if tier is Tier.DECTED:
+        return {"ecc": dected_encode_words(
+            lo, hi, block_rows=bm).astype(jnp.uint16)}
+    if tier is Tier.BURST:
+        return {"ecc": burst_encode_words(
+            lo, hi, block_rows=bm).astype(jnp.uint16)}
+    if tier is Tier.PARITY_R:
+        return {"par": parity_encode_words(
+            lo, hi, block_rows=bm).astype(jnp.uint8)}
+    if tier is Tier.MIRROR:
+        return {"copy_lo": lo, "copy_hi": hi,
+                "par": parity_encode_words(
+                    lo, hi, block_rows=bm).astype(jnp.uint8)}
+    raise ValueError(tier)
+
+
 @functools.lru_cache(maxsize=None)
 def _compiled_encode(spec: DomainSpec, key: Optional[Tuple[str, ...]]
                      ) -> Callable:
@@ -409,36 +436,18 @@ def _compiled_encode(spec: DomainSpec, key: Optional[Tuple[str, ...]]
     sidecar with only the selected rows rewritten.
     """
     selected = spec.select(key)
-    partial = key is not None
 
-    def encode_tier(tier, leaves, sel, padded, bm):
+    def encode_tier(tier, leaves, sel, padded):
         lo, hi = _gather_packed(leaves, sel, padded)
-        if tier is Tier.SECDED:
-            return {"ecc": secded_encode_words(
-                lo, hi, block_rows=bm).astype(jnp.uint8)}
-        if tier is Tier.DECTED:
-            return {"ecc": dected_encode_words(
-                lo, hi, block_rows=bm).astype(jnp.uint16)}
-        if tier is Tier.BURST:
-            return {"ecc": burst_encode_words(
-                lo, hi, block_rows=bm).astype(jnp.uint16)}
-        if tier is Tier.PARITY_R:
-            return {"par": parity_encode_words(
-                lo, hi, block_rows=bm).astype(jnp.uint8)}
-        if tier is Tier.MIRROR:
-            return {"copy_lo": lo, "copy_hi": hi,
-                    "par": parity_encode_words(
-                        lo, hi, block_rows=bm).astype(jnp.uint8)}
-        raise ValueError(tier)
+        return _encode_tier(tier, lo, hi, _block_rows(padded))
 
-    if not partial:
+    if key is None:
         def fn_full(leaves):
             sc = {}
             for tier in _tier_order(selected):
                 padded, _ = spec.groups[tier]
-                sc[tier.value] = encode_tier(
-                    tier, leaves, selected[tier], padded,
-                    _block_rows(padded))
+                sc[tier.value] = encode_tier(tier, leaves, selected[tier],
+                                             padded)
             return sc
         return _jit_named(fn_full, f"{spec.kind}_encode")
 
@@ -447,15 +456,73 @@ def _compiled_encode(spec: DomainSpec, key: Optional[Tuple[str, ...]]
         for tier in _tier_order(selected):
             sel = selected[tier]
             total = sum(s.rows for s in sel)
-            padded = _round_rows(total)
-            fresh = encode_tier(tier, leaves, sel, padded,
-                                _block_rows(padded))
+            fresh = encode_tier(tier, leaves, sel, _round_rows(total))
             for name, new in fresh.items():
                 new_sc[tier.value][name] = _scatter_rows(
                     sidecar[tier.value][name], sel, new[:total])
         return new_sc
 
     return _jit_named(fn_partial, f"{spec.kind}_encode_rows")
+
+
+def _slice_rows(s: LeafSpec) -> int:
+    """Packed rows per index of the leaf's second axis, or 0 where such a
+    slice is not a whole number of rows (or the leaf has no second axis).
+    Row ``r`` holds the leaf's flat bytes ``8 LANES r ..``, so slice ``i``
+    of leading index ``l`` is rows ``(l n1 + i) R .. + R``."""
+    if len(s.shape) < 2:
+        return 0
+    nbytes = jnp.dtype(s.dtype).itemsize
+    for d in s.shape[2:]:
+        nbytes *= d
+    rows, rem = divmod(nbytes, 8 * LANES)
+    return rows if not rem else 0
+
+
+@functools.lru_cache(maxsize=None)
+def _compiled_encode_slices(spec: DomainSpec) -> Callable:
+    """One jit program re-encoding the sidecar rows of index slices along
+    the second axis of every protected leaf (each slice a whole number of
+    packed rows: ``DomainSpec.slices_aligned``).
+
+    fn(leaves, sidecar, idx) -> sidecar, the sidecar donated so the
+    scatter updates its buffers in place. Only ``leaf[:, idx]`` is gathered
+    and packed, and its fresh code rows are scattered to the slices' rows;
+    a repeated index writes the same rows twice. The gather is cheap where
+    the second axis is not the most minor in device memory: see
+    ``serve.paged_kv`` for the pool layout that keeps it so on a TPU.
+    Every tier's code is per 64-bit word, so the result is the full
+    encode's, bit for bit, wherever only those slices changed. It is the
+    write-path encode narrowed, and lowers under the same name.
+    """
+    selected = spec.select(None)
+
+    def fn(leaves, sidecar, idx):
+        new_sc = {k: dict(v) for k, v in sidecar.items()}
+        for tier in _tier_order(selected):
+            los, his, at = [], [], []
+            for s in selected[tier]:
+                r = _slice_rows(s)
+                n = s.shape[0] * idx.shape[0] * r
+                p = ops.pack_words(leaves[s.pos][:, idx])
+                los.append(p.lo[:n])
+                his.append(p.hi[:n])
+                first = (jnp.arange(s.shape[0], dtype=jnp.int32)[:, None]
+                         * s.shape[1] + idx[None, :]) * r + s.row_start
+                at.append((first[:, :, None]
+                           + jnp.arange(r, dtype=jnp.int32)).reshape(-1))
+            at = jnp.concatenate(at)
+            total = at.shape[0]
+            padded = _round_rows(total)
+            fresh = _encode_tier(tier, _concat_pad(los, padded),
+                                 _concat_pad(his, padded),
+                                 _block_rows(padded))
+            for name, new in fresh.items():
+                new_sc[tier.value][name] = \
+                    sidecar[tier.value][name].at[at].set(new[:total])
+        return new_sc
+
+    return _jit_named(fn, f"{spec.kind}_encode", donate_argnums=(1,))
 
 
 # =====================================================================
@@ -676,6 +743,33 @@ class MemoryDomain:
             if not key:
                 return dom
             sidecar = _compiled_encode(dom.spec, key)(leaves, dom.sidecar)
+        return MemoryDomain(dom.payload, sidecar, dom.hard_errors, dom.spec)
+
+    def refresh_pages(self, pages, state=None) -> "MemoryDomain":
+        """Re-encode sidecars after writes confined to index slices
+        ``pages`` along the second axis of every protected leaf: the page
+        axis of a paged KV pool ``(layers, n_pages, ...)``. Only those
+        slices are packed and encoded, and the sidecar comes out as a
+        full ``refresh`` would leave it, bit for bit. Each slice has to be
+        a whole number of packed rows (``DomainSpec.slices_aligned``),
+        else ValueError: the caller chooses the full ``refresh`` then.
+        The sidecar is updated in place: this domain's (and any domain's
+        that shares it) is spent."""
+        dom = self if state is None else self.adopt(state)
+        if not dom.spec.groups:
+            return dom
+        if not dom.spec.slices_aligned():
+            raise ValueError("a slice along the second axis is not a whole "
+                             "number of packed rows: use refresh()")
+        idx = np.asarray(pages, np.int32).reshape(-1)
+        if not idx.size:
+            return dom
+        n1 = min(s.shape[1] for ls in dom.spec.select(None).values()
+                 for s in ls)
+        if idx.min() < 0 or idx.max() >= n1:
+            raise IndexError(f"page index out of range [0, {n1})")
+        sidecar = _compiled_encode_slices(dom.spec)(
+            tuple(dom._leaves()), dom.sidecar, jnp.asarray(idx))
         return MemoryDomain(dom.payload, sidecar, dom.hard_errors, dom.spec)
 
     # ------------------------------------------------------ injection

@@ -16,12 +16,17 @@ Two memory domains, mirroring the paper's region split:
             of the next step* (access-path ECC) — injected strikes always
             land between a refresh and the next check, so they are
             detected (parity) or corrected (SEC-DED), never laundered.
+            The refresh encodes only the pages the step wrote (its
+            prefills' prompt pages and one page per slot of the decode),
+            where a page is a whole number of packed sidecar rows, and
+            the whole pool after a strike, a check that found errors, a
+            recovery or a crash.
 
-The decode step is one jit program over every scheduler slot: gather each
-slot's pages into a contiguous view, one-hot-insert the new token's K/V
-(the same update the contiguous oracle uses), attend under the per-slot
-validity mask, and scatter the new K/V back to its page. The gathered
-view reproduces the contiguous cache bit-for-bit, so paged decode is
+The decode step is one jit program over every scheduler slot: write the
+new token's K/V into its page, gather each slot's pages into a contiguous
+view (the contiguous oracle's cache after its update), and attend under
+the per-slot validity mask. The gathered view reproduces the contiguous
+cache bit-for-bit wherever the mask admits it, so paged decode is
 bit-identical to ``runtime.serve_loop.serve_batch``
 (``tests/test_serve_plane.py`` pins this).
 
@@ -112,7 +117,7 @@ def _make_paged_decode(cfg: ModelConfig, page_size: int):
     if cfg.family not in ("dense", "moe", "vlm"):
         raise ValueError(f"paged decode supports dense/moe/vlm, "
                          f"not {cfg.family!r}")
-    dh, H = cfg.head_dim, cfg.n_heads
+    dh, H, K = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
     cdt = dtype_of(cfg.compute_dtype)
 
     def serve_decode(params, pool_k, pool_v, table, tokens, pos):
@@ -129,15 +134,16 @@ def _make_paged_decode(cfg: ModelConfig, page_size: int):
             h = rmsnorm(x, layer["norm1"], cfg.norm_eps)
             q, k_new, v_new = attn._project_qkv(
                 layer["attn"], h, cfg, positions)
-            # page gather -> contiguous (S, smax, K, dh) view
-            vk = pk[table].reshape(S, smax, *pk.shape[2:])
-            vv = pv[table].reshape(S, smax, *pv.shape[2:])
-            # one-hot insert of the new token (the contiguous oracle's
-            # dynamic_update_slice, batched over per-slot positions)
-            upd = (jnp.arange(smax)[None, :]
-                   == pos[:, None])[:, :, None, None]
-            vk = jnp.where(upd, k_new.astype(vk.dtype), vk)
-            vv = jnp.where(upd, v_new.astype(vv.dtype), vv)
+            # write the new token's K/V into its page first (inactive
+            # slots land in the null page, read only where masked), then
+            # gather each slot's pages into the contiguous (S, smax, K,
+            # dh) view: the oracle's cache with the token inserted
+            pk = pk.at[pid, off].set(k_new[:, 0].reshape(S, -1)
+                                     .astype(pk.dtype))
+            pv = pv.at[pid, off].set(v_new[:, 0].reshape(S, -1)
+                                     .astype(pv.dtype))
+            vk = pk[table].reshape(S, smax, K, dh)
+            vv = pv[table].reshape(S, smax, K, dh)
             scores = jnp.einsum("bqkgd,bskd->bkgqs", q,
                                 vk.astype(q.dtype)).astype(jnp.float32)
             scores = scores / math.sqrt(dh)
@@ -158,10 +164,6 @@ def _make_paged_decode(cfg: ModelConfig, page_size: int):
                     layer["mlp"], rmsnorm(x, layer["norm2"], cfg.norm_eps),
                     cfg)
             x = x + h2
-            # scatter the new K/V into its page (inactive slots land in
-            # the null page and are never read unmasked)
-            pk = pk.at[pid, off].set(k_new[:, 0].astype(pk.dtype))
-            pv = pv.at[pid, off].set(v_new[:, 0].astype(pv.dtype))
             return x, (pk, pv)
 
         x, (pk, pv) = jax.lax.scan(
@@ -196,8 +198,8 @@ def _make_prefill_write(cfg: ModelConfig, page_size: int):
         v = jnp.where(keep, cache["v"], 0).astype(pool_v.dtype)[:, 0]
         L = k.shape[0]
         n_pp = pages.shape[0]
-        k = k.reshape(L, n_pp, page_size, *k.shape[2:])
-        v = v.reshape(L, n_pp, page_size, *v.shape[2:])
+        k = k.reshape(L, n_pp, page_size, -1)
+        v = v.reshape(L, n_pp, page_size, -1)
         pool_k = pool_k.at[:, pages].set(k)
         pool_v = pool_v.at[:, pages].set(v)
         return pool_k, pool_v, first, jnp.isfinite(last).all()
@@ -279,6 +281,11 @@ class OnlineEngine:
         self.kv_domain = MemoryDomain.protect(
             {"kv_cache": {"k": self.cache.pool_k,
                           "v": self.cache.pool_v}}, kv_policy(kv_tier))
+        # page-only refresh where a page is whole sidecar rows; a strike,
+        # a check that found errors, a recovery or a crash leaves the
+        # sidecar stale beyond the written pages until a full refresh
+        self._kv_paged = self.kv_domain.spec.slices_aligned()
+        self._kv_stale = False
 
         self._decode = _decode_program(cfg, page_size)
         self._prefill = _prefill_program(cfg, page_size)
@@ -341,6 +348,7 @@ class OnlineEngine:
             kv = self.kv_domain.payload["kv_cache"]
             self.cache.adopt_pools(kv["k"], kv["v"])
             counters.injected_kv += 1
+            self._kv_stale = True
 
     def _inject_bound(self, strike: BoundStrike, counters: SLOCounters
                       ) -> None:
@@ -356,6 +364,7 @@ class OnlineEngine:
             kv = self.kv_domain.payload["kv_cache"]
             self.cache.adopt_pools(kv["k"], kv["v"])
             counters.injected_kv += 1
+            self._kv_stale = True
 
     def _scrub_params(self, counters: SLOCounters) -> None:
         self.param_domain, rep = self.param_domain.scrub()
@@ -384,6 +393,9 @@ class OnlineEngine:
         counters.kv_corrected += c
         counters.kv_detected += u
         changed = bool(c)                # SEC-DED repaired pool words
+        # errors found: the full refresh re-encodes the pool, so a Par+R
+        # detection with no recovery is counted once, not every check
+        self._kv_stale |= bool(c or u)
         needs = rep.needs_recovery()
         if self.peer_recovery and needs and self._kv_peer is not None:
             # the peer snapshot is the post-refresh pool image — the
@@ -423,6 +435,7 @@ class OnlineEngine:
                                               kv_policy(self.kv_tier))
         if self.kv_tier is not Tier.NONE:
             counters.kv_pages_encoded += self.cache.n_pages
+        self._kv_stale = True
         self._kv_peer = None             # stale after the restart
 
     # ---------------------------------------------------------- iteration
@@ -430,6 +443,7 @@ class OnlineEngine:
                    storm: deque, now: float, it: int) -> float:
         """One engine iteration; returns the served clock after it."""
         n_pages = self.cache.n_pages
+        written: List[np.ndarray] = []   # pages this iteration writes
         # 1. access-path KV check: catches strikes injected after the
         #    previous refresh, before any re-encode can launder them
         if self.kv_tier is not Tier.NONE:
@@ -459,8 +473,9 @@ class OnlineEngine:
             pages = self.cache.alloc(slot, req.footprint_tokens())
             first, ok = self._run_prefill(req, pages)
             counters.prefills += 1
-            counters.kv_pages_written += self.cache.pages_needed(
-                req.prompt_len)
+            n_pp = self.cache.pages_needed(req.prompt_len)
+            counters.kv_pages_written += n_pp
+            written.append(pages[:n_pp])
             now = self._advance(now, self.service.prefill_cost(
                 req.prompt_len))
             if not ok:
@@ -474,6 +489,10 @@ class OnlineEngine:
         if self.sched.n_active:
             tokens, pos = self.sched.batch_inputs()
             active = self.sched.n_active
+            # the page each slot's new K/V lands in (the null page for
+            # an inactive slot): every slot writes one
+            written.append(self.cache.table[
+                np.arange(len(pos)), pos // self._page_size])
             with TraceAnnotation("serve.decode", active=active):
                 pk, pv, nxt, ok = self._decode(
                     self._params(), self.cache.pool_k, self.cache.pool_v,
@@ -495,11 +514,23 @@ class OnlineEngine:
                 self._origin -= nxt_t - now  # idle: jump to next arrival
                 now = nxt_t
         # 5. write-path ECC: re-encode the KV sidecar over this
-        #    step's legitimate writes
+        #    step's legitimate writes, the pages written or the pool
         if self.kv_tier is not Tier.NONE:
-            with TraceAnnotation("serve.kv_refresh", pages=n_pages):
-                self.kv_domain = self.kv_domain.refresh(self._kv_state())
-            counters.kv_pages_encoded += n_pages
+            full = self._kv_stale or not self._kv_paged
+            n_enc = n_pages if full else sum(len(w) for w in written)
+            with TraceAnnotation("serve.kv_refresh", pages=n_enc,
+                                 full=int(full)):
+                if full:
+                    self.kv_domain = self.kv_domain.refresh(
+                        self._kv_state())
+                else:
+                    dom = self.kv_domain.adopt(self._kv_state())
+                    for w in written:
+                        dom = dom.refresh_pages(w)
+                    self.kv_domain = dom
+            counters.kv_pages_encoded += n_enc
+            counters.kv_full_refreshes += int(full)
+            self._kv_stale = False
         else:
             self.kv_domain = self.kv_domain.adopt(self._kv_state())
         if self.peer_recovery:

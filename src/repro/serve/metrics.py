@@ -38,11 +38,16 @@ class SLOCounters:
     kv_corrected: int = 0
     kv_detected: int = 0
     # KV pages coded by the protection passes, against those the model
-    # wrote: each check and refresh covers the whole pool; a prefill
-    # writes its prompt pages, a decode step one page per active slot
+    # wrote: each check covers the whole pool; a refresh encodes the
+    # pages of its iteration's prefills and one per slot of its decode
+    # (the null page for an inactive slot), or the whole pool when it
+    # falls back to a full refresh (counted in kv_full_refreshes); a
+    # prefill writes its prompt pages, a decode step one page per
+    # active slot
     kv_pages_checked: int = 0
     kv_pages_encoded: int = 0
     kv_pages_written: int = 0
+    kv_full_refreshes: int = 0
     recovery_events: int = 0
     peer_recovery_events: int = 0
     crash_events: int = 0

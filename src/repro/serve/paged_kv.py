@@ -1,8 +1,12 @@
 """Paged KV cache: fixed-size pages, a host-side free-list allocator, and
 device pools that register as their own ``MemoryDomain`` root.
 
-Layout: two pools ``(n_layers, n_pages, page_size, n_kv_heads, head_dim)``
-(keys and values). Page 0 is the reserved *null* page — page-table slots
+Layout: two pools ``(n_layers, n_pages, page_size, n_kv_heads * head_dim)``
+(keys and values), the heads and their width merged into one trailing
+axis so that a page is lane-dense: a TPU lays out an array whose
+trailing axes are narrower than its 128-lane tile (heads of 64) with the
+page axis in lanes, and then taking one page reads every tile of the
+pool. Page 0 is the reserved *null* page — page-table slots
 that a request has not grown into yet point at it, and decode steps of
 inactive scheduler slots write their garbage K/V there. The null page is
 only ever read at attention positions past a slot's current length, where
@@ -45,8 +49,9 @@ class PagedKVCache:
             raise ValueError("need at least one real page beside the null "
                              "page")
         cdt = dtype_of(cfg.compute_dtype)
-        shape = (cfg.n_layers, n_pages, page_size, cfg.n_kv_heads,
-                 cfg.head_dim)
+        shape = (cfg.n_layers, n_pages, page_size,
+                 cfg.n_kv_heads * cfg.head_dim)
+        self.heads = (cfg.n_kv_heads, cfg.head_dim)
         self.pool_k = jnp.zeros(shape, cdt)
         self.pool_v = jnp.zeros(shape, cdt)
         self.page_size = page_size
@@ -117,9 +122,9 @@ class PagedKVCache:
         n = self.pages_needed(length)
         pages = self.table[slot, :n]
         k = self.pool_k[:, pages].reshape(
-            self.pool_k.shape[0], 1, -1, *self.pool_k.shape[3:])
+            self.pool_k.shape[0], 1, -1, *self.heads)
         v = self.pool_v[:, pages].reshape(
-            self.pool_v.shape[0], 1, -1, *self.pool_v.shape[3:])
+            self.pool_v.shape[0], 1, -1, *self.heads)
         return k[:, :, :length], v[:, :, :length]
 
     # --------------------------------------------------------- invariants

@@ -15,15 +15,18 @@ from jax.profiler import ProfileData
 
 from repro.configs import get_tiny
 from repro.core import MemoryDomain, Tier, detect_recover_l
-from repro.core.domain import (_compiled_encode, _compiled_scrub,
-                               _compiled_scrub_rows)
+from repro.core.domain import (_compiled_encode, _compiled_encode_slices,
+                               _compiled_scrub, _compiled_scrub_rows)
 from repro.graph import graph_state, pagerank_scrubbed, powerlaw_graph
 from repro.graph.pagerank import _region_paths
 from repro.models import init_params
 from repro.serve import OnlineEngine, Request
 
-CFG = get_tiny("llama3-8b")
+# 2 KV heads of 64 in bf16: a page of 8 tokens is 2 KiB, one packed row,
+# so the engine re-encodes only the pages it wrote
+CFG = get_tiny("llama3-8b").replace(d_head=64)
 PAGE = 8
+SLOTS = 2
 SERVE_SPANS = ("serve.kv_check", "serve.params_scrub", "serve.prefill",
                "serve.decode", "serve.kv_refresh", "serve.inject")
 GRAPH_SPANS = ("graph.step", "graph.rank_encode", "graph.scrub_slice")
@@ -46,7 +49,7 @@ def _wave(n, prompt_lens=(8, 13), max_new=(4, 6), seed=1):
 def _engine(params, kv_tier=Tier.PARITY_R, **kw):
     kw.setdefault("policy", detect_recover_l())
     kw.setdefault("scrub_every", 2)
-    return OnlineEngine(CFG, params, slots=2, page_size=PAGE,
+    return OnlineEngine(CFG, params, slots=SLOTS, page_size=PAGE,
                         max_prompt_len=16, max_new_cap=8, kv_tier=kv_tier,
                         seed=0, **kw)
 
@@ -108,6 +111,17 @@ def test_program_module_names(params, graph, which):
     lowered = (_lower_graph(graph, which) if which.startswith("graph")
                else _lower_serve(params, which))
     assert _module_name(lowered) == f"jit_{which}"
+
+
+def test_page_refresh_lowers_as_cache_encode(params):
+    """The page-only KV refresh is the write-path encode, narrowed: it
+    lowers under the full encode's name, so the device trace counts it
+    as the KV refresh."""
+    dom = _engine(params).kv_domain
+    assert dom.spec.slices_aligned()
+    lowered = _compiled_encode_slices(dom.spec).lower(
+        tuple(dom._leaves()), dom.sidecar, jnp.zeros(SLOTS, jnp.int32))
+    assert _module_name(lowered) == "jit_cache_encode"
 
 
 def test_domain_kind_from_roots(params):
@@ -188,8 +202,23 @@ def test_serve_spans_per_iteration(captures):
     assert _count(ev, "serve.inject") == 1
     assert rep.counters["injected_params"] + rep.counters["injected_kv"] \
         == 2 and rep.counters["crash_events"] == 0
-    assert all(s["pages"] == eng.cache.n_pages for *_, name, s in ev
-               if name == "serve.kv_refresh")
+    # each refresh encodes its iteration's prompt pages and one page per
+    # slot of its decode, or the whole pool in the one iteration after a
+    # KV strike
+    full = 0
+    for a, b, *_ in iters:
+        inside = [(name, s) for a2, b2, name, s in ev if a <= a2 and b2 <= b]
+        (refresh,) = [s for name, s in inside if name == "serve.kv_refresh"]
+        full += refresh["full"]
+        assert refresh["pages"] == (eng.cache.n_pages if refresh["full"]
+                                    else sum(s["pages"] for name, s in inside
+                                             if name == "serve.prefill")
+                                    + SLOTS)
+    assert full == rep.counters["kv_full_refreshes"] == \
+        min(rep.counters["injected_kv"], 1)
+    assert sum(s["pages"] for *_, name, s in ev
+               if name == "serve.kv_refresh") == \
+        rep.counters["kv_pages_encoded"]
     written = sum(s["pages"] for *_, name, s in ev
                   if name == "serve.prefill") + \
         sum(s["active"] for *_, name, s in ev if name == "serve.decode")
@@ -214,11 +243,17 @@ def test_kv_page_counters_exact(params, kv_tier):
     iters = c["decode_steps"]             # every request is due at t=0
     pages = eng.cache.n_pages
     on = kv_tier is not Tier.NONE
-    assert c["kv_pages_encoded"] == (iters * pages if on else 0)
-    assert c["kv_pages_checked"] == ((iters + 1) * pages if on else 0)
     prompt_pages = sum(-(-r.prompt_len // PAGE) for r in trace)
+    # the refresh encodes the prompt pages and one page per slot of each
+    # decode, never the whole pool
+    assert c["kv_pages_encoded"] == (prompt_pages + iters * SLOTS
+                                     if on else 0)
+    assert c["kv_full_refreshes"] == 0
+    assert c["kv_pages_checked"] == ((iters + 1) * pages if on else 0)
     decoded = sum(r.max_new - 1 for r in trace)
     assert c["kv_pages_written"] == prompt_pages + decoded
+    if on:
+        assert c["kv_pages_encoded"] <= 2 * c["kv_pages_written"]
 
 
 def test_serve_online_json_has_page_counters(tmp_path, capsys):

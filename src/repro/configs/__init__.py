@@ -1,6 +1,6 @@
 from repro.configs.base import (  # noqa: F401
-    MeshConfig, ModelConfig, MoEConfig, SHAPES, SHAPE_BY_NAME, SINGLE_POD,
-    MULTI_POD, SSMConfig, ShapeSpec, TrainConfig, XLSTMConfig,
+    MeshConfig, MLAConfig, ModelConfig, MoEConfig, SHAPES, SHAPE_BY_NAME,
+    SINGLE_POD, MULTI_POD, SSMConfig, ShapeSpec, TrainConfig, XLSTMConfig,
     shape_applicability,
 )
 from repro.configs.registry import (  # noqa: F401

@@ -23,6 +23,44 @@ class MoEConfig:
     router_aux_weight: float = 0.01
     capacity_factor: float = 1.25  # used by the dropping dispatch path
     dispatch: str = "dense"      # "dense" (einsum masking) | "a2a" (EP all-to-all)
+    norm_topk: bool = True       # renormalize the top-k gates to sum to 1
+    # the experts this chip holds, of the router's n_experts: experts
+    # first_held .. first_held + n_held - 1 (n_held 0: all of them).
+    # Expert parallelism's share: the router still scores every expert,
+    # and the layer returns the held experts' part of the result
+    first_held: int = 0
+    n_held: int = 0
+
+    @property
+    def n_local(self) -> int:
+        return self.n_held or self.n_experts
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2): keys and values come
+    from one cached latent per token, ``kv_lora_rank`` wide after its
+    RMSNorm, beside a ``qk_rope_head_dim`` rotary key shared by the heads.
+    Rotary positions follow YaRN (``rope_factor`` over
+    ``rope_original_max``, the ramp between the correction dims of
+    ``beta_fast`` and ``beta_slow``); ``mscale_all_dim`` scales the
+    softmax. Prefill attends in query blocks of ``q_block``."""
+
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    rope_factor: float = 1.0
+    rope_original_max: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+    q_block: int = 1024
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
 
 
 @dataclass(frozen=True)
@@ -67,6 +105,8 @@ class ModelConfig:
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
     xlstm: Optional[XLSTMConfig] = None
+    mla: Optional[MLAConfig] = None  # latent attention in place of GQA
+    n_dense_layers: int = 0      # moe: leading dense layers (MLP of d_ff)
     attn_every: int = 0          # hybrid: shared attn block every k mixer layers
     frontend: str = "none"       # none | audio_frames | vision_patches
     n_patches: int = 0           # vlm: image patch embeddings prepended to text

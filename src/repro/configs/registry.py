@@ -15,6 +15,7 @@ _MODULES: Dict[str, str] = {
     "zamba2-2.7b": "zamba2_2p7b",
     "granite-moe-3b-a800m": "granite_moe_3b_a800m",
     "deepseek-moe-16b": "deepseek_moe_16b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
     "llama3-405b": "llama3_405b",
     "nemotron-4-340b": "nemotron_4_340b",
     "llama3-8b": "llama3_8b",
@@ -30,7 +31,7 @@ _MODULES: Dict[str, str] = {
 ASSIGNED_ARCHS: List[str] = [
     "zamba2-2.7b", "granite-moe-3b-a800m", "deepseek-moe-16b", "llama3-405b",
     "nemotron-4-340b", "llama3-8b", "qwen2-72b", "hubert-xlarge",
-    "xlstm-350m", "llava-next-mistral-7b",
+    "xlstm-350m", "llava-next-mistral-7b", "deepseek-v2-lite",
 ]
 
 

@@ -14,6 +14,10 @@ stack/heap/private classification) are derived from pytree paths:
     params/norm    norms and other small vectors
     opt/m, opt/v   optimizer moments
     kv_cache       decode KV cache / recurrent states
+    kv_cache/latent  latent-attention cache: one latent word feeds the
+                   keys and values of every head of its token, where a
+                   GQA word feeds one KV head (same bytes, different
+                   vulnerability)
     activations    transient per-step tensors (policy is advisory: they are
                    never scrubbed, only accounted in the cost model)
 """
@@ -29,7 +33,8 @@ from repro.core.tiers import Tier
 
 REGIONS = ("params/embed", "params/attn", "params/mlp", "params/experts",
            "params/ssm", "params/norm", "opt/m", "opt/v", "kv_cache",
-           "activations", "graph/topology", "graph/rank", "graph/frontier")
+           "kv_cache/latent", "activations", "graph/topology", "graph/rank",
+           "graph/frontier")
 
 _SSM_KEYS = ("mamba", "mlstm", "slstm", "conv_w", "conv_b", "a_log",
              "dt_bias", "d_skip")
@@ -37,7 +42,8 @@ _EMBED_KEYS = ("embed", "head", "patch_proj", "frame_proj")
 _ATTN_KEYS = ("attn", "wq", "wk", "wv", "wo", "bq", "bk", "bv")
 _EXPERT_KEYS = ("moe", "experts", "router")
 _CACHE_KEYS = ("k", "v", "attn_k", "attn_v", "mamba_conv", "mamba_ssm",
-               "m_conv", "m_c", "s_c", "s_n", "s_h", "s_m")
+               "m_conv", "m_c", "s_c", "s_n", "s_h", "s_m", "c_kv", "k_pe")
+_LATENT_KEYS = ("c_kv", "k_pe")
 _GRAPH_TOPO_KEYS = ("topology", "indptr", "indices", "src", "dst", "outdeg")
 _GRAPH_FRONTIER_KEYS = ("frontier", "visited", "dist")
 
@@ -60,7 +66,8 @@ def classify_path(path, root: str = "params") -> str:
     if root == "opt":
         return "opt/m" if keys and keys[0] in ("m", "mu") else "opt/v"
     if root == "cache":
-        return "kv_cache"
+        return ("kv_cache/latent" if set(keys) & set(_LATENT_KEYS)
+                else "kv_cache")
     if root == "graph":
         ks = set(keys)
         if ks & set(_GRAPH_TOPO_KEYS):
@@ -69,6 +76,8 @@ def classify_path(path, root: str = "params") -> str:
             return "graph/frontier"
         return "graph/rank"
     ks = set(keys)
+    if "moe" in ks and "shared" in ks:
+        return "params/mlp"             # shared experts run on every token
     if ks & set(_EXPERT_KEYS):
         return "params/experts"
     if ks & set(_SSM_KEYS):

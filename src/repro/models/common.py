@@ -57,11 +57,17 @@ def rope_freqs(head_dim: int, theta: float):
 
 def apply_rope(x, positions, theta: float):
     """x: (..., S, H, Dh); positions: broadcastable to (..., S)."""
-    dh = x.shape[-1]
-    freqs = rope_freqs(dh, theta)                       # (Dh/2,)
+    return apply_rope_freqs(x, positions, rope_freqs(x.shape[-1], theta))
+
+
+def apply_rope_freqs(x, positions, freqs, scale: float = 1.0):
+    """Half-split rotation of x (..., S, H, Dh) by ``freqs`` (Dh/2,);
+    ``scale`` multiplies cos and sin (YaRN's attention factor)."""
     ang = positions[..., None].astype(jnp.float32) * freqs   # (..., S, Dh/2)
     ang = ang[..., None, :]                             # (..., S, 1, Dh/2)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

@@ -51,13 +51,15 @@ def mlp_apply(p, x, cfg: ModelConfig):
 
 # ----------------------------------------------------------------- MoE MLP
 def moe_init(key, cfg: ModelConfig):
+    """The router scores all ``n_experts``; the expert stacks hold the
+    ``n_local`` experts this chip holds."""
     moe = cfg.moe
     D, E, Fe = cfg.d_model, moe.n_experts, moe.d_expert
     pdt = dtype_of(cfg.param_dtype)
     ks = jax.random.split(key, 5)
 
     def expert_stack(k, d_in, d_out, scale=None):
-        kk = jax.random.split(k, E)
+        kk = jax.random.split(k, E)[moe.first_held:][:moe.n_local]
         return jax.vmap(lambda kx: dense_init(kx, d_in, d_out, pdt,
                                               scale=scale))(kk)
 
@@ -80,6 +82,11 @@ def _capacity(T: int, moe) -> int:
 
 def moe_apply(p, x, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
     """x: (B,S,D) -> (y (B,S,D), aux_loss scalar).
+
+    The layer holds experts ``first_held`` .. of the router's
+    ``n_experts`` (all by default): it routes every token over all of
+    them and returns the held experts' part of the result, plus the
+    shared experts; a token's other top-k experts are another chip's.
 
     Under ``shard_hints`` with an ambient mesh, dispatch runs *locally per
     data shard* via shard_map (tokens never cross the data axis; the
@@ -143,24 +150,30 @@ def _moe_dispatch_tokens(p, xt, cfg: ModelConfig
     logits = (xt.astype(jnp.float32) @ p["router"])          # (T, E)
     gates = jax.nn.softmax(logits, axis=-1)
     topw, tope = jax.lax.top_k(gates, K)                     # (T, K)
-    topw = topw / jnp.maximum(topw.sum(-1, keepdims=True), 1e-9)
+    if moe.norm_topk:
+        topw = topw / jnp.maximum(topw.sum(-1, keepdims=True), 1e-9)
 
     # load-balancing aux loss (Switch-style)
     density = jnp.mean(jax.nn.one_hot(tope[:, 0], E), axis=0)
     mean_gate = jnp.mean(gates, axis=0)
     aux = E * jnp.sum(density * mean_gate)
 
-    # ---- sort-based grouped dispatch
+    # ---- sort-based grouped dispatch over the held experts
     C = _capacity(T, moe)
     fe = tope.reshape(-1)                                    # (T*K,) expert ids
+    if moe.n_local < E:
+        # held experts by their local index; another chip's go last
+        fe = fe - moe.first_held
+        fe = jnp.where((fe >= 0) & (fe < moe.n_local), fe, moe.n_local)
+        E = moe.n_local
     fw = topw.reshape(-1)
     ftok = jnp.arange(T * K) // K                            # source token ids
     order = jnp.argsort(fe, stable=True)                     # group by expert
     fe_s, fw_s, ftok_s = fe[order], fw[order], ftok[order]
     # slot within expert = sorted rank - start offset of that expert group
     starts = jnp.searchsorted(fe_s, jnp.arange(E))           # (E,)
-    slot = jnp.arange(T * K) - starts[fe_s]
-    keep = slot < C
+    slot = jnp.arange(T * K) - starts[jnp.minimum(fe_s, E - 1)]
+    keep = (slot < C) & (fe_s < E)
     row = jnp.where(keep, fe_s, E)                           # overflow row E
     col = jnp.where(keep, slot, 0)
 
@@ -199,9 +212,11 @@ def moe_apply_dense(p, x, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
     logits = xt.astype(jnp.float32) @ p["router"]
     gates = jax.nn.softmax(logits, axis=-1)
     topw, tope = jax.lax.top_k(gates, K)
-    topw = topw / jnp.maximum(topw.sum(-1, keepdims=True), 1e-9)
+    if moe.norm_topk:
+        topw = topw / jnp.maximum(topw.sum(-1, keepdims=True), 1e-9)
     w_full = jnp.zeros_like(gates)
     w_full = jax.vmap(lambda w, t, g: w.at[t].set(g))(w_full, tope, topw)
+    w_full = w_full[:, moe.first_held:moe.first_held + moe.n_local]
 
     h = jnp.einsum("td,edf->etf", xt, p["wi"].astype(cdt))
     h = jax.nn.silu(h) * jnp.einsum("td,edf->etf", xt, p["wg"].astype(cdt))

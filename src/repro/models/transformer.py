@@ -43,17 +43,23 @@ def init_params(key: jax.Array, cfg: ModelConfig) -> Params:
         p["patch_proj"] = dense_init(ks[5], cfg.d_model, cfg.d_model, pdt)
 
     if cfg.family in ("dense", "moe", "audio", "vlm"):
-        def one_layer(k):
+        def one_layer(k, moe=cfg.family == "moe"):
             k1, k2 = jax.random.split(k)
             block = {"norm1": jnp.ones((cfg.d_model,), pdt),
-                     "attn": attn.attn_init(k1, cfg),
+                     "attn": (attn.mla_init(k1, cfg) if cfg.mla
+                              else attn.attn_init(k1, cfg)),
                      "norm2": jnp.ones((cfg.d_model,), pdt)}
-            if cfg.family == "moe":
+            if moe:
                 block["moe"] = mlp_mod.moe_init(k2, cfg)
             else:
                 block["mlp"] = mlp_mod.mlp_init(k2, cfg)
             return block
-        p["blocks"] = stacked_init(one_layer, ks[1], cfg.n_layers)
+        p["blocks"] = stacked_init(one_layer, ks[1],
+                                   cfg.n_layers - cfg.n_dense_layers)
+        if cfg.n_dense_layers:
+            # leading dense layers, before the scanned expert blocks
+            p["dense"] = stacked_init(
+                lambda k: one_layer(k, moe=False), ks[6], cfg.n_dense_layers)
 
     elif cfg.family == "hybrid":
         def one_layer(k):
@@ -130,20 +136,24 @@ def _maybe_remat(fn, remat: str):
 
 # ================================================================== forward
 def forward(p: Params, batch: Dict[str, jax.Array], cfg: ModelConfig,
-            remat: str = "none", return_cache: bool = False):
-    """Full-sequence forward. Returns (logits, aux_loss, cache|None)."""
+            remat: str = "none", return_cache: bool = False,
+            logits_at: Optional[jax.Array] = None):
+    """Full-sequence forward. Returns (logits, aux_loss, cache|None); with
+    ``logits_at`` (n,) positions, the logits (B, n, V) at those only."""
     x, _, _ = _embed_inputs(p, batch, cfg)
     B, S, D = x.shape
     positions = jnp.arange(S)[None, :]
 
     if cfg.family in ("dense", "moe", "audio", "vlm"):
+        attend = attn.mla_apply if cfg.mla else attn.attn_apply
+
         def body(carry, layer):
             x, aux = carry
-            h, (k, v) = attn.attn_apply(
+            h, kv = attend(
                 layer["attn"], rmsnorm(x, layer["norm1"], cfg.norm_eps),
                 cfg, positions)
             x = x + h
-            if cfg.family == "moe":
+            if "moe" in layer:
                 h, a = mlp_mod.moe_apply(
                     layer["moe"], rmsnorm(x, layer["norm2"], cfg.norm_eps), cfg)
                 aux = aux + a
@@ -151,13 +161,18 @@ def forward(p: Params, batch: Dict[str, jax.Array], cfg: ModelConfig,
                 h = mlp_mod.mlp_apply(
                     layer["mlp"], rmsnorm(x, layer["norm2"], cfg.norm_eps), cfg)
             x = x + h
-            return (x, aux), (k, v) if return_cache else None
+            return (x, aux), kv if return_cache else None
 
-        (x, aux), caches = jax.lax.scan(
-            _maybe_remat(body, remat), (x, jnp.float32(0.0)), p["blocks"])
+        carry, caches = (x, jnp.float32(0.0)), []
+        for stack in _layer_stacks(p, cfg):
+            carry, c = jax.lax.scan(_maybe_remat(body, remat), carry, stack)
+            caches.append(c)
+        x, aux = carry
         cache = None
         if return_cache:
-            cache = {"k": caches[0], "v": caches[1]}       # (L,B,S,K,dh)
+            # (L,B,S,K,dh) keys and values, or (L,B,S,r) latents and
+            # (L,B,S,dr) rotary keys
+            cache = dict(zip(cache_names(cfg), _cat_layers(caches)))
 
     elif cfg.family == "hybrid":
         G = cfg.n_layers // cfg.attn_every
@@ -221,8 +236,29 @@ def forward(p: Params, batch: Dict[str, jax.Array], cfg: ModelConfig,
     else:
         raise ValueError(cfg.family)
 
+    if logits_at is not None:
+        x = jnp.take(x, logits_at, axis=1)
     logits = _head(p, x, cfg)
     return logits, aux, cache
+
+
+def cache_names(cfg: ModelConfig) -> Tuple[str, str]:
+    """The attention cache's two leaves: keys and values, or the latent
+    and the shared rotary key of latent attention."""
+    return ("c_kv", "k_pe") if cfg.mla else ("k", "v")
+
+
+def _layer_stacks(p: Params, cfg: ModelConfig):
+    """The stacked layer groups in order: leading dense layers, then the
+    blocks."""
+    return ([p["dense"]] if cfg.n_dense_layers else []) + [p["blocks"]]
+
+
+def _cat_layers(parts):
+    """Per-group scan outputs joined along the layer axis."""
+    if len(parts) == 1:
+        return parts[0]
+    return jax.tree.map(lambda *a: jnp.concatenate(a, axis=0), *parts)
 
 
 # ==================================================================== loss
@@ -251,6 +287,11 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int):
     cdt = dtype_of(cfg.compute_dtype)
     dh, Kh = cfg.head_dim, cfg.n_kv_heads
     if cfg.family in ("dense", "moe", "vlm"):
+        if cfg.mla:
+            lead = (cfg.n_layers, batch, max_seq)
+            return {"c_kv": jnp.zeros(lead + (cfg.mla.kv_lora_rank,), cdt),
+                    "k_pe": jnp.zeros(lead + (cfg.mla.qk_rope_head_dim,),
+                                      cdt)}
         shape = (cfg.n_layers, batch, max_seq, Kh, dh)
         return {"k": jnp.zeros(shape, cdt), "v": jnp.zeros(shape, cdt)}
     if cfg.family == "hybrid":
@@ -285,13 +326,16 @@ def decode_step(p: Params, token: jax.Array, pos: jax.Array, cache,
     x = p["embed"][token][:, None, :].astype(cdt)          # (B,1,D)
 
     if cfg.family in ("dense", "moe", "vlm"):
+        attend = attn.mla_decode if cfg.mla else attn.attn_decode
+        names = cache_names(cfg)
+
         def body(x, xs):
             layer, kc, vc = xs
-            h, kc, vc = attn.attn_decode(
+            h, kc, vc = attend(
                 layer["attn"], rmsnorm(x, layer["norm1"], cfg.norm_eps),
                 kc, vc, pos, cfg)
             x = x + h
-            if cfg.family == "moe":
+            if "moe" in layer:
                 h, _ = mlp_mod.moe_apply(
                     layer["moe"], rmsnorm(x, layer["norm2"], cfg.norm_eps), cfg)
             else:
@@ -300,9 +344,14 @@ def decode_step(p: Params, token: jax.Array, pos: jax.Array, cache,
             x = x + h
             return x, (kc, vc)
 
-        x, (k, v) = jax.lax.scan(body, x, (p["blocks"], cache["k"],
-                                           cache["v"]))
-        cache = {"k": k, "v": v}
+        parts, lo = [], 0
+        for stack in _layer_stacks(p, cfg):
+            n = jax.tree.leaves(stack)[0].shape[0]
+            x, kv = jax.lax.scan(body, x, (stack,) + tuple(
+                cache[k][lo:lo + n] for k in names))
+            parts.append(kv)
+            lo += n
+        cache = dict(zip(names, _cat_layers(parts)))
 
     elif cfg.family == "hybrid":
         G = cfg.n_layers // cfg.attn_every

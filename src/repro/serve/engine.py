@@ -9,7 +9,9 @@ Two memory domains, mirroring the paper's region split:
             the policy cadence; Par+R detections reload from a clean copy
             and charge ``RECOVERY_SECONDS`` of measured downtime).
   kv_cache  the paged KV pools — the Fig. 4 largest, most error-tolerant
-            region, under a configurable cheap tier. Unlike params, the
+            region, under a configurable cheap tier; a latent-attention
+            model's latent pools are a region of their own
+            (``kv_cache/latent``) under the same tier. Unlike params, the
             pools are written every step, so ECC is emulated the way the
             hardware does it: the sidecar is re-encoded after each step's
             legitimate writes (write-path ECC) and *checked at the start
@@ -28,7 +30,11 @@ view (the contiguous oracle's cache after its update), and attend under
 the per-slot validity mask. The gathered view reproduces the contiguous
 cache bit-for-bit wherever the mask admits it, so paged decode is
 bit-identical to ``runtime.serve_loop.serve_batch``
-(``tests/test_serve_plane.py`` pins this).
+(``tests/test_serve_plane.py`` pins this). Under latent attention the
+step writes the token's latent and rotary key into their pages, then
+attends the gathered latents in absorbed form (the query through the
+key half of ``wkv_b``, the weighted latents through its value half),
+where the prefill runs the expanded form; the two agree to rounding.
 
 Time: the engine advances a virtual clock by a calibrated service model
 (``--clock model``, deterministic — the CI/test path) or reads the wall
@@ -43,7 +49,9 @@ Tracing: each iteration is a ``serve.iteration`` step span holding one
 span per phase (``serve.kv_check``, ``serve.params_scrub``,
 ``serve.prefill``, ``serve.decode``, ``serve.kv_refresh``,
 ``serve.inject``), and the device programs lower as ``jit_serve_decode``
-and ``jit_serve_prefill``; both cost nothing without a profiler.
+and ``jit_serve_prefill``; both cost nothing without a profiler. The
+decode span carries ``active`` (occupied slots) and ``ctx_tokens`` (the
+cached tokens the step reads: each active slot's position plus one).
 """
 from __future__ import annotations
 
@@ -67,9 +75,10 @@ from repro.models import forward
 from repro.models import attention as attn
 from repro.models import mlp as mlp_mod
 from repro.models.common import dtype_of, rmsnorm
-from repro.models.transformer import _head
+from repro.models.transformer import _head, _layer_stacks, cache_names
 from repro.serve.metrics import SLOCounters, SLOReport, build_report
-from repro.serve.paged_kv import PagedKVCache
+from repro.serve.paged_kv import (PagedKVCache, pack_rope,
+                                  rope_tokens_per_row)
 from repro.serve.router import RequestRouter
 from repro.serve.scheduler import ContinuousBatchingScheduler
 from repro.serve.traffic import Request
@@ -95,8 +104,10 @@ class ServiceModel:
 
 
 def kv_policy(tier: Tier) -> HRMPolicy:
-    """Policy for the KV domain: one region, one (cheap) tier."""
-    tiers = {} if tier is Tier.NONE else {"kv_cache": tier}
+    """Policy for the KV domain: one (cheap) tier over the KV pools and
+    the latent pools alike."""
+    tiers = ({} if tier is Tier.NONE
+             else {"kv_cache": tier, "kv_cache/latent": tier})
     return HRMPolicy(f"kv_{tier.value}", tiers, default=Tier.NONE,
                      scrub_interval=1)
 
@@ -104,11 +115,21 @@ def kv_policy(tier: Tier) -> HRMPolicy:
 # =====================================================================
 # jitted programs (shared across engine instances via lru_cache)
 # =====================================================================
+def _ffn(layer, x, cfg: ModelConfig):
+    h = rmsnorm(x, layer["norm2"], cfg.norm_eps)
+    if "moe" in layer:
+        return mlp_mod.moe_apply(layer["moe"], h, cfg)[0]
+    return mlp_mod.mlp_apply(layer["mlp"], h, cfg)
+
+
 def _make_paged_decode(cfg: ModelConfig, page_size: int):
     """One fused decode step over every slot against the paged pools.
 
-    (params, pool_k, pool_v, table, tokens, pos)
-      -> (pool_k', pool_v', next_tokens, ok)
+    (params, pool_a, pool_b, table, tokens, pos)
+      -> (pool_a', pool_b', next_tokens, ok)
+
+    The two pools are the cache's, in ``cache_names`` order: keys and
+    values, or latents and rotary keys.
 
     The attention math mirrors ``models.attention.attn_decode`` line for
     line on the gathered contiguous view, so results are bit-identical to
@@ -117,6 +138,8 @@ def _make_paged_decode(cfg: ModelConfig, page_size: int):
     if cfg.family not in ("dense", "moe", "vlm"):
         raise ValueError(f"paged decode supports dense/moe/vlm, "
                          f"not {cfg.family!r}")
+    if cfg.mla:
+        return _make_paged_decode_mla(cfg, page_size)
     dh, H, K = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
     cdt = dtype_of(cfg.compute_dtype)
 
@@ -155,15 +178,7 @@ def _make_paged_decode(cfg: ModelConfig, page_size: int):
                                                                H * dh)
             y = o.astype(x.dtype) @ layer["attn"]["wo"].astype(x.dtype)
             x = x + y
-            if cfg.family == "moe":
-                h2, _ = mlp_mod.moe_apply(
-                    layer["moe"], rmsnorm(x, layer["norm2"], cfg.norm_eps),
-                    cfg)
-            else:
-                h2 = mlp_mod.mlp_apply(
-                    layer["mlp"], rmsnorm(x, layer["norm2"], cfg.norm_eps),
-                    cfg)
-            x = x + h2
+            x = x + _ffn(layer, x, cfg)
             return x, (pk, pv)
 
         x, (pk, pv) = jax.lax.scan(
@@ -176,45 +191,145 @@ def _make_paged_decode(cfg: ModelConfig, page_size: int):
     return serve_decode
 
 
+def _make_paged_decode_mla(cfg: ModelConfig, page_size: int):
+    """The decode step under latent attention (``_paged_logits_mla``),
+    with the greedy next tokens."""
+    step = _paged_logits_mla(cfg, page_size)
+
+    def serve_decode(params, c_kv, k_pe, table, tokens, pos):
+        pools, logits = step(params, {"c_kv": c_kv, "k_pe": k_pe}, table,
+                             tokens, pos)
+        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return (pools["c_kv"], pools["k_pe"], nxt,
+                jnp.isfinite(logits).all())
+
+    return serve_decode
+
+
+def _paged_logits_mla(cfg: ModelConfig, page_size: int):
+    """(params, pools, table, tokens, pos) -> (pools', logits (S, V)).
+
+    Per layer, write each slot's latent and rotary key into its page
+    (inactive slots into the null page), gather each slot's pages, and
+    attend in absorbed form. The pools ride in the layer loop's carry and
+    are indexed by layer, so the leading dense layers and the expert
+    blocks update one pool in place. The rotary keys stay packed ``g`` to
+    a row: the query's rotary part is laid out block-diagonally against a
+    row's ``g`` tokens, so the scores come out per token without
+    unpacking the view."""
+    m, H = cfg.mla, cfg.n_heads
+    r, dr = m.kv_lora_rank, m.qk_rope_head_dim
+    g = rope_tokens_per_row(cfg)
+    cdt = dtype_of(cfg.compute_dtype)
+
+    def layer(x, l, lp, pools, at):
+        table, pid, off, positions, valid = at
+        S, P = table.shape
+        c_pool, pe_pool = pools["c_kv"], pools["k_pe"]
+        q_nope, q_pe, c_new, pe_new = attn.mla_project(
+            lp["attn"], rmsnorm(x, lp["norm1"], cfg.norm_eps), cfg,
+            positions)
+        c_pool = c_pool.at[l, pid, off].set(c_new[:, 0].astype(c_pool.dtype))
+        lanes = (off % g)[:, None] * dr + jnp.arange(dr)
+        pe_pool = pe_pool.at[l, pid[:, None], (off // g)[:, None], lanes].set(
+            pe_new[:, 0].astype(pe_pool.dtype))
+        c_view = c_pool[l, table].reshape(S, P * page_size, r)
+        q_rows = jnp.einsum("jk,shd->shjkd", jnp.eye(g, dtype=q_pe.dtype),
+                            q_pe[:, 0]).reshape(S, H, g, g * dr)
+        s_pe = jnp.einsum("shjl,sprl->shprj", q_rows,
+                          pe_pool[l, table].astype(q_pe.dtype),
+                          preferred_element_type=jnp.float32)
+        y = attn.mla_attend_absorbed(
+            lp["attn"], attn.mla_absorb_query(lp["attn"], q_nope[:, 0], cfg),
+            s_pe.reshape(S, H, P * page_size), c_view, valid, cfg)
+        x = x + y.astype(x.dtype)
+        x = x + _ffn(lp, x, cfg)
+        return x, {"c_kv": c_pool, "k_pe": pe_pool}
+
+    def step(params, pools, table, tokens, pos):
+        S, P = table.shape
+        x = params["embed"][tokens][:, None, :].astype(cdt)    # (S,1,D)
+        pid = jnp.take_along_axis(
+            table, (pos // page_size)[:, None], axis=1)[:, 0]  # (S,)
+        valid = jnp.arange(P * page_size)[None, :] <= pos[:, None]
+        at = (table, pid, pos % page_size, pos[:, None], valid)
+        lo = 0
+        for stack in _layer_stacks(params, cfg):
+            n = jax.tree.leaves(stack)[0].shape[0]
+
+            def body(carry, xs):
+                x, pools = carry
+                lp, l = xs
+                return layer(x, l, lp, pools, at), None
+
+            (x, pools), _ = jax.lax.scan(
+                body, (x, pools), (stack, lo + jnp.arange(n)))
+            lo += n
+        return pools, _head(params, x, cfg)[:, 0]              # (S,V)
+
+    return step
+
+
 def _make_prefill_write(cfg: ModelConfig, page_size: int):
     """Prefill one request (padded to a whole number of pages) and write
-    its prompt K/V into the allocated pages.
+    its prompt's cache into the allocated pages.
 
-    (params, pool_k, pool_v, tokens(1,Sb), true_len, pages(n_pp,))
-      -> (pool_k', pool_v', first_token, ok)
+    (params, pool_a, pool_b, tokens(1,Sb), true_len, pages(n_pp,))
+      -> (pool_a', pool_b', first_token, ok)
+
+    Under latent attention only the last prompt position goes through
+    the head: a long prompt's logits over a large vocabulary would
+    outweigh its cache.
     """
 
-    def serve_prefill(params, pool_k, pool_v, tokens, true_len, pages):
+    names = cache_names(cfg)
+
+    def serve_prefill(params, pool_a, pool_b, tokens, true_len, pages):
+        last_at = (true_len - 1)[None] if cfg.mla else None
         logits, _, cache = forward(params, {"tokens": tokens}, cfg,
-                                   return_cache=True)
-        last = jax.lax.dynamic_index_in_dim(logits[0], true_len - 1,
-                                            axis=0, keepdims=False)
+                                   return_cache=True, logits_at=last_at)
+        last = jax.lax.dynamic_index_in_dim(
+            logits[0], 0 if cfg.mla else true_len - 1, axis=0,
+            keepdims=False)
         first = jnp.argmax(last, axis=-1).astype(jnp.int32)
         # zero the padded tail so page contents match the contiguous
         # oracle's zero-initialized cache bit-for-bit
-        keep = (jnp.arange(tokens.shape[1])
-                < true_len)[None, None, :, None, None]
-        k = jnp.where(keep, cache["k"], 0).astype(pool_k.dtype)[:, 0]
-        v = jnp.where(keep, cache["v"], 0).astype(pool_v.dtype)[:, 0]
-        L = k.shape[0]
+        keep = jnp.arange(tokens.shape[1]) < true_len
         n_pp = pages.shape[0]
-        k = k.reshape(L, n_pp, page_size, -1)
-        v = v.reshape(L, n_pp, page_size, -1)
-        pool_k = pool_k.at[:, pages].set(k)
-        pool_v = pool_v.at[:, pages].set(v)
-        return pool_k, pool_v, first, jnp.isfinite(last).all()
+        out = []
+        for name, pool in zip(names, (pool_a, pool_b)):
+            c = cache[name]
+            c = jnp.where(keep.reshape((1, 1, -1) + (1,) * (c.ndim - 3)),
+                          c, 0).astype(pool.dtype)[:, 0]
+            c = c.reshape(c.shape[0], n_pp, page_size, -1)
+            if name == "k_pe":
+                c = pack_rope(c, rope_tokens_per_row(cfg))
+            out.append(pool.at[:, pages].set(c))
+        return out[0], out[1], first, jnp.isfinite(last).all()
 
     return serve_prefill
 
 
+def _donates_pools(cfg: ModelConfig) -> bool:
+    """Whether the serving programs take the pools over (latent
+    attention): the decode's layer loop then updates them in place, and
+    a prefill writes only its pages, where an undonated pool is copied
+    whole on every call. Whoever still holds the old pools (the KV
+    domain's payload until the refresh adopts the new ones) must not
+    read them."""
+    return cfg.mla is not None
+
+
 @functools.lru_cache(maxsize=None)
 def _decode_program(cfg: ModelConfig, page_size: int):
-    return jax.jit(_make_paged_decode(cfg, page_size))
+    return jax.jit(_make_paged_decode(cfg, page_size),
+                   donate_argnums=(1, 2) if _donates_pools(cfg) else ())
 
 
 @functools.lru_cache(maxsize=None)
 def _prefill_program(cfg: ModelConfig, page_size: int):
-    return jax.jit(_make_prefill_write(cfg, page_size))
+    return jax.jit(_make_prefill_write(cfg, page_size),
+                   donate_argnums=(1, 2) if _donates_pools(cfg) else ())
 
 
 # =====================================================================
@@ -278,9 +393,8 @@ class OnlineEngine:
                             else (policy.scrub_interval if policy else 0))
 
         # KV domain: its own root over the page pools
-        self.kv_domain = MemoryDomain.protect(
-            {"kv_cache": {"k": self.cache.pool_k,
-                          "v": self.cache.pool_v}}, kv_policy(kv_tier))
+        self.kv_domain = MemoryDomain.protect(self._kv_state(),
+                                              kv_policy(kv_tier))
         # page-only refresh where a page is whole sidecar rows; a strike,
         # a check that found errors, a recovery or a crash leaves the
         # sidecar stale beyond the written pages until a full refresh
@@ -296,8 +410,17 @@ class OnlineEngine:
         return self.param_domain.payload
 
     def _kv_state(self) -> dict:
-        return {"kv_cache": {"k": self.cache.pool_k,
-                             "v": self.cache.pool_v}}
+        return {"kv_cache": dict(self.cache.pools)}
+
+    def _adopt_kv_payload(self) -> None:
+        self.cache.adopt_pools(self.kv_domain.payload["kv_cache"])
+
+    def _pools(self) -> tuple:
+        """The two pools in the programs' argument order."""
+        return tuple(self.cache.pools[n] for n in cache_names(self.cfg))
+
+    def _adopt(self, a, b) -> None:
+        self.cache.adopt_pools(dict(zip(cache_names(self.cfg), (a, b))))
 
     def _advance(self, now: float, model_cost: float) -> float:
         """The served clock at a host sync: the wall time since ``run``
@@ -311,8 +434,20 @@ class OnlineEngine:
         ps = self.param_domain.stats()
         ks = self.kv_domain.stats()
         pol = self.params_policy.name if self.params_policy else "none"
+        leaves = []
+        for s in self.kv_domain.spec.leaves:
+            side = 0
+            if s.tier is not Tier.NONE:
+                # the leaf's share of its tier's sidecar, by packed rows
+                buf = self.kv_domain.sidecar[s.tier.value]
+                side = s.rows * sum(v.nbytes // v.shape[0]
+                                    for v in buf.values())
+            leaves.append(f"  {s.path} {s.shape} {s.dtype} [{s.region}, "
+                          f"{s.tier.value}]: payload={s.nbytes}B "
+                          f"sidecar={side}B")
         return (f"params[{pol}]: {ps.summary()}\n"
                 f"kv_cache[{self.kv_tier.value}]: {ks.summary()}\n"
+                + "\n".join(leaves) + "\n"
                 f"pages={self.cache.n_pages} x {self._page_size} tokens, "
                 f"slots={self.cache.slots}, "
                 f"max_pages/slot={self.cache.max_pages_per_slot}")
@@ -327,13 +462,12 @@ class OnlineEngine:
         tokens[0, :req.prompt_len] = req.prompt
         with TraceAnnotation("serve.prefill", rid=req.rid,
                              prompt_len=req.prompt_len, pages=n_pp):
-            pk, pv, first, ok = self._prefill(
-                self._params(), self.cache.pool_k, self.cache.pool_v,
-                jnp.asarray(tokens), jnp.int32(req.prompt_len),
-                jnp.asarray(pages[:n_pp]))
+            a, b, first, ok = self._prefill(
+                self._params(), *self._pools(), jnp.asarray(tokens),
+                jnp.int32(req.prompt_len), jnp.asarray(pages[:n_pp]))
             first = int(first)
             ok = bool(ok)
-        self.cache.adopt_pools(pk, pv)
+        self._adopt(a, b)
         return first, ok
 
     # -------------------------------------------------------- fault plane
@@ -345,8 +479,7 @@ class OnlineEngine:
             counters.injected_params += 1
         else:
             self.kv_domain, _ = self.kv_domain.inject(self.rng, 1)
-            kv = self.kv_domain.payload["kv_cache"]
-            self.cache.adopt_pools(kv["k"], kv["v"])
+            self._adopt_kv_payload()
             counters.injected_kv += 1
             self._kv_stale = True
 
@@ -361,8 +494,7 @@ class OnlineEngine:
         else:
             self.kv_domain = self.kv_domain.apply_plan(
                 strike.path, strike.plan(), record_hard=strike.hard)
-            kv = self.kv_domain.payload["kv_cache"]
-            self.cache.adopt_pools(kv["k"], kv["v"])
+            self._adopt_kv_payload()
             counters.injected_kv += 1
             self._kv_stale = True
 
@@ -409,8 +541,7 @@ class OnlineEngine:
             counters.charge_peer_recoveries(len(events))
             changed = True
         if changed:
-            kv = self.kv_domain.payload["kv_cache"]
-            self.cache.adopt_pools(kv["k"], kv["v"])
+            self._adopt_kv_payload()
 
     def _crash_reset(self, router: RequestRouter, counters: SLOCounters
                      ) -> None:
@@ -429,8 +560,8 @@ class OnlineEngine:
         assert clean == {s.path for s in self.param_domain.spec.leaves}
         for req in reversed(self.sched.evict_all()):
             router.requeue(req)
-        self.cache.adopt_pools(jnp.zeros_like(self.cache.pool_k),
-                               jnp.zeros_like(self.cache.pool_v))
+        self.cache.adopt_pools({n: jnp.zeros_like(p)
+                                for n, p in self.cache.pools.items()})
         self.kv_domain = MemoryDomain.protect(self._kv_state(),
                                               kv_policy(self.kv_tier))
         if self.kv_tier is not Tier.NONE:
@@ -489,19 +620,24 @@ class OnlineEngine:
         if self.sched.n_active:
             tokens, pos = self.sched.batch_inputs()
             active = self.sched.n_active
+            # cached tokens the step reads: positions 0..pos of each
+            # active slot, its new token included
+            ctx = sum(s.pos + 1 for s in self.sched.slots if s is not None)
             # the page each slot's new K/V lands in (the null page for
             # an inactive slot): every slot writes one
             written.append(self.cache.table[
                 np.arange(len(pos)), pos // self._page_size])
-            with TraceAnnotation("serve.decode", active=active):
-                pk, pv, nxt, ok = self._decode(
-                    self._params(), self.cache.pool_k, self.cache.pool_v,
+            with TraceAnnotation("serve.decode", active=active,
+                                 ctx_tokens=ctx):
+                a, b, nxt, ok = self._decode(
+                    self._params(), *self._pools(),
                     self.cache.device_table(), jnp.asarray(tokens),
                     jnp.asarray(pos))
                 nxt = np.asarray(nxt)
                 ok = bool(ok)
-            self.cache.adopt_pools(pk, pv)
+            self._adopt(a, b)
             counters.decode_steps += 1
+            counters.decode_ctx_tokens += ctx
             counters.kv_pages_written += active    # one page per slot
             now = self._advance(now, self.service.decode_cost(active))
             if ok:
@@ -536,8 +672,10 @@ class OnlineEngine:
         if self.peer_recovery:
             # peer image: a replica that doesn't take this storm's
             # strikes holds exactly this post-write pool state
-            self._kv_peer = {"kv_cache/k": self.cache.pool_k,
-                             "kv_cache/v": self.cache.pool_v}
+            # (a copy where the next step takes the pools over)
+            keep = jnp.copy if _donates_pools(self.cfg) else (lambda a: a)
+            self._kv_peer = {f"kv_cache/{n}": keep(p)
+                             for n, p in self.cache.pools.items()}
         # 6. the storm: fire every error due by the current clock
         if storm and storm[0][0] <= now:
             with TraceAnnotation("serve.inject"):

@@ -48,6 +48,9 @@ class SLOCounters:
     kv_pages_encoded: int = 0
     kv_pages_written: int = 0
     kv_full_refreshes: int = 0
+    # cached tokens the decode steps read: per step, each active slot's
+    # position plus one (the ``ctx_tokens`` of the ``serve.decode`` span)
+    decode_ctx_tokens: int = 0
     recovery_events: int = 0
     peer_recovery_events: int = 0
     crash_events: int = 0

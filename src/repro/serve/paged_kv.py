@@ -1,12 +1,21 @@
 """Paged KV cache: fixed-size pages, a host-side free-list allocator, and
 device pools that register as their own ``MemoryDomain`` root.
 
-Layout: two pools ``(n_layers, n_pages, page_size, n_kv_heads * head_dim)``
-(keys and values), the heads and their width merged into one trailing
-axis so that a page is lane-dense: a TPU lays out an array whose
-trailing axes are narrower than its 128-lane tile (heads of 64) with the
-page axis in lanes, and then taking one page reads every tile of the
-pool. Page 0 is the reserved *null* page — page-table slots
+Layout: named pools of ``(n_layers, n_pages, ...)``, their leaves chosen
+by the attention kind (``pool_shapes``), each page lane-dense: a TPU lays
+out an array whose trailing axes are narrower than its 128-lane tile
+(heads of 64) with the page axis in lanes, and then taking one page
+reads every tile of the pool.
+
+- grouped-query attention: ``k`` and ``v``, ``(L, pages, page_size,
+  n_kv_heads * head_dim)``, the heads and their width merged;
+- latent attention: ``c_kv``, ``(L, pages, page_size, kv_lora_rank)``,
+  the normed latent, and ``k_pe``, the rotary key shared by the heads,
+  ``128 // qk_rope_head_dim`` tokens to a 128-lane row: ``(L, pages,
+  page_size * qk_rope_head_dim // 128, 128)``, the row-major bytes of
+  ``(L, pages, page_size, qk_rope_head_dim)`` (``pack_rope``).
+
+Page 0 is the reserved *null* page — page-table slots
 that a request has not grown into yet point at it, and decode steps of
 inactive scheduler slots write their garbage K/V there. The null page is
 only ever read at attention positions past a slot's current length, where
@@ -27,7 +36,7 @@ and the free list and page tables exactly partition the pool (no leaks).
 """
 from __future__ import annotations
 
-from typing import Dict, List
+from typing import Dict, List, Tuple
 
 import jax.numpy as jnp
 import numpy as np
@@ -36,6 +45,37 @@ from repro.configs.base import ModelConfig
 from repro.models.common import dtype_of
 
 NULL_PAGE = 0
+ROW_LANES = 128                 # a TPU tile's lanes
+
+
+def rope_tokens_per_row(cfg: ModelConfig) -> int:
+    """Tokens of the latent cache's rotary key packed into one row."""
+    dr = cfg.mla.qk_rope_head_dim
+    if ROW_LANES % dr:
+        raise ValueError(f"qk_rope_head_dim {dr} does not divide "
+                         f"{ROW_LANES} lanes")
+    return ROW_LANES // dr
+
+
+def pool_shapes(cfg: ModelConfig, n_pages: int, page_size: int
+                ) -> Dict[str, Tuple[int, ...]]:
+    """The pools' names and shapes for the attention kind."""
+    lead = (cfg.n_layers, n_pages)
+    if cfg.mla:
+        g = rope_tokens_per_row(cfg)
+        if page_size % g:
+            raise ValueError(f"a page of {page_size} tokens is not whole "
+                             f"rows of {g} rotary keys")
+        return {"c_kv": lead + (page_size, cfg.mla.kv_lora_rank),
+                "k_pe": lead + (page_size // g, ROW_LANES)}
+    width = cfg.n_kv_heads * cfg.head_dim
+    return {"k": lead + (page_size, width), "v": lead + (page_size, width)}
+
+
+def pack_rope(k_pe, g: int):
+    """(..., tokens, dr) rotary keys -> (..., tokens // g, g dr) rows."""
+    *lead, t, dr = k_pe.shape
+    return k_pe.reshape(*lead, t // g, g * dr)
 
 
 class PagedKVCache:
@@ -49,11 +89,9 @@ class PagedKVCache:
             raise ValueError("need at least one real page beside the null "
                              "page")
         cdt = dtype_of(cfg.compute_dtype)
-        shape = (cfg.n_layers, n_pages, page_size,
-                 cfg.n_kv_heads * cfg.head_dim)
-        self.heads = (cfg.n_kv_heads, cfg.head_dim)
-        self.pool_k = jnp.zeros(shape, cdt)
-        self.pool_v = jnp.zeros(shape, cdt)
+        self.pools: Dict[str, jnp.ndarray] = {
+            name: jnp.zeros(shape, cdt)
+            for name, shape in pool_shapes(cfg, n_pages, page_size).items()}
         self.page_size = page_size
         self.n_pages = n_pages
         self.slots = slots
@@ -111,21 +149,10 @@ class PagedKVCache:
     def device_table(self) -> jnp.ndarray:
         return jnp.asarray(self.table)
 
-    def adopt_pools(self, pool_k, pool_v) -> None:
+    def adopt_pools(self, pools: Dict[str, jnp.ndarray]) -> None:
         """Take updated device pools back from a jitted step."""
-        self.pool_k = pool_k
-        self.pool_v = pool_v
-
-    def contiguous_view(self, slot: int, length: int) -> tuple:
-        """Gather one slot's first ``length`` positions back into the
-        contiguous ``(L, 1, length, K, dh)`` layout (test oracle glue)."""
-        n = self.pages_needed(length)
-        pages = self.table[slot, :n]
-        k = self.pool_k[:, pages].reshape(
-            self.pool_k.shape[0], 1, -1, *self.heads)
-        v = self.pool_v[:, pages].reshape(
-            self.pool_v.shape[0], 1, -1, *self.heads)
-        return k[:, :, :length], v[:, :, :length]
+        assert pools.keys() == self.pools.keys()
+        self.pools = dict(pools)
 
     # --------------------------------------------------------- invariants
     def check_invariants(self) -> None:

@@ -75,12 +75,13 @@ def _lower_serve(params, which):
     eng = _engine(params)
     if which == "serve_decode":
         tokens, pos = np.zeros(2, np.int32), np.zeros(2, np.int32)
-        return eng._decode.lower(eng._params(), eng.cache.pool_k,
-                                 eng.cache.pool_v, eng.cache.device_table(),
+        return eng._decode.lower(eng._params(), eng.cache.pools["k"],
+                                 eng.cache.pools["v"],
+                                 eng.cache.device_table(),
                                  jnp.asarray(tokens), jnp.asarray(pos))
     if which == "serve_prefill":
-        return eng._prefill.lower(eng._params(), eng.cache.pool_k,
-                                  eng.cache.pool_v,
+        return eng._prefill.lower(eng._params(), eng.cache.pools["k"],
+                                  eng.cache.pools["v"],
                                   jnp.zeros((1, PAGE), jnp.int32),
                                   jnp.int32(5), jnp.zeros(1, jnp.int32))
     dom = eng.param_domain if which == "params_scrub" else eng.kv_domain

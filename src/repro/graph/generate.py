@@ -37,7 +37,8 @@ from typing import Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.segsum import EDGE_TILE, NODE_LANES, _round_up, pad_edges
+from repro.kernels.segsum import (EDGE_TILE, NODE_LANES, TILES_PER_STEP,
+                                  _round_up, pad_edges)
 
 # below this node count the O(n^2) legacy sampling loop is cheap and its
 # exact edge stream is pinned by existing tests; above it the vectorized
@@ -120,7 +121,8 @@ def bucket_edges(src, dst, n_pad: int, node_block: int, *,
     Every bucket is sentinel-padded (id ``n_pad``: block-local out of
     range for *every* block) to whole ``edge_tile`` tiles, so each tile
     lives in exactly one bucket; buckets are laid out dst-block-major
-    (the kernel's output-revisit contract). Returns
+    (the kernel's output-revisit contract), in whole grid steps of
+    ``TILES_PER_STEP`` tiles. Returns
     ``(src, dst, tile_src_block, tile_dst_block)`` — edge arrays of shape
     (T*edge_tile,) plus the (T,) per-tile dispatch tables. Fully
     vectorized: O(E log E) at build time.
@@ -147,6 +149,17 @@ def bucket_edges(src, dst, n_pad: int, node_block: int, *,
     tiles_per = padded // edge_tile
     tile_db = np.repeat(uk // nb, tiles_per).astype(np.int32)
     tile_sb = np.repeat(uk % nb, tiles_per).astype(np.int32)
+    # whole grid steps of the push (TILES_PER_STEP tiles, one edge DMA),
+    # which are also whole 1024-word tiles of a TPU's (E,) int32 layout
+    # (else the push's (1, E) view re-lays both arrays out every call).
+    # Extra tiles are all sentinel and repeat the last tile's blocks.
+    extra = -tile_db.size % TILES_PER_STEP
+    if extra:
+        fill = np.full(extra * edge_tile, n_pad, np.int32)
+        out_src = np.concatenate([out_src, fill])
+        out_dst = np.concatenate([out_dst, fill])
+        tile_sb = np.concatenate([tile_sb, np.repeat(tile_sb[-1:], extra)])
+        tile_db = np.concatenate([tile_db, np.repeat(tile_db[-1:], extra)])
     return out_src, out_dst, tile_sb, tile_db
 
 

@@ -17,7 +17,7 @@ from repro.graph import (bfs, bfs_reference, bfs_scrubbed, bucket_edges,
                          pagerank_scrubbed, powerlaw_graph, top_k)
 from repro.graph.bfs import active_src_blocks
 from repro.graph.pagerank import _pagerank_fori, _region_paths, _step_math
-from repro.kernels.segsum import (EDGE_TILE, NODE_LANES,
+from repro.kernels.segsum import (EDGE_TILE, NODE_LANES, TILES_PER_STEP,
                                   edge_segment_push_blocked,
                                   edge_segment_push_blocked_oracle,
                                   edge_segment_push_blocked_ref,
@@ -78,6 +78,26 @@ def test_bucket_edges_preserves_and_sorts():
     assert np.all(bdst[real] // bn == db_e[real])
     # sentinel is block-local out of range for every block
     assert np.all(bsrc[~real] == n_pad)
+    assert tsb.shape[0] % TILES_PER_STEP == 0     # whole push steps
+
+
+def test_bucket_edges_whole_push_steps():
+    """Three buckets of one 512-edge tile each round up to one grid step
+    of the push, TILES_PER_STEP tiles: the added tiles are all sentinel,
+    keep the dst-block-major order and repeat the last tile's blocks."""
+    n_pad, bn = 384, 128
+    src, dst = np.array([0, 130, 260]), np.array([0, 0, 300])
+    bsrc, bdst, tsb, tdb = bucket_edges(src, dst, n_pad, bn, edge_tile=512)
+    extra = TILES_PER_STEP - 3
+    assert tsb.tolist() == [0, 1] + [2] * (1 + extra)
+    assert tdb.tolist() == [0, 0] + [2] * (1 + extra)
+    assert bsrc.shape[0] == TILES_PER_STEP * 512
+    assert np.all(bsrc[3 * 512:] == n_pad) and np.all(bdst[3 * 512:] == n_pad)
+    y = edge_segment_push_blocked(jnp.asarray(bsrc), jnp.asarray(bdst),
+                                  jnp.asarray(tsb), jnp.asarray(tdb),
+                                  jnp.ones((1, n_pad), jnp.float32),
+                                  node_block=bn)
+    assert float(y.sum()) == 3.0
 
 
 def test_bucket_edges_degenerate_empty():
@@ -116,6 +136,43 @@ def test_blocked_push_matches_oracle_and_ref(seed, n, e, bni, te, corrupt):
     yr = edge_segment_push_blocked_ref(*args, node_block=bni)
     np.testing.assert_allclose(y, yo, rtol=ORACLE_RTOL, atol=0)
     assert jnp.allclose(y, yr, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("bn", [1024, 8192])
+def test_blocked_push_row_lane_id_edges(bn):
+    """The kernel splits a block-local id l into row l >> 7 and lane
+    l & 127. Edges on the id edges 0, 127, 128, BN-1 (kept) and BN, -1
+    (outside the tile's block: dropped), among ids spread over every row,
+    in groups of tiles that span up to three destination blocks and leave
+    one block unvisited, match the oracle to a few ulp and the segment_sum
+    ref."""
+    rng = np.random.default_rng(bn)
+    te, n_blocks = 256, 4
+    edge_ids = np.array([0, 127, 128, bn - 1, bn, -1])
+    db = np.array([0, 0, 1, 2, 2, 2, 2, 2, 2, 2, 2, 2], np.int32)
+    sb = rng.integers(0, n_blocks, db.size).astype(np.int32)
+    src = rng.integers(0, bn, (db.size, te))
+    dst = rng.integers(0, bn, (db.size, te))
+    src[:, :edge_ids.size] = edge_ids
+    dst[:, :edge_ids.size] = np.roll(edge_ids, 1)
+    src[:, edge_ids.size:2 * edge_ids.size] = edge_ids[::-1]
+    dst[:, edge_ids.size:2 * edge_ids.size] = edge_ids
+    src = (src + sb[:, None] * bn).astype(np.int32).reshape(-1)
+    dst = (dst + db[:, None] * bn).astype(np.int32).reshape(-1)
+    x = jnp.asarray(rng.random((1, n_blocks * bn)), jnp.float32)
+    args = (jnp.asarray(src), jnp.asarray(dst), jnp.asarray(sb),
+            jnp.asarray(db), x)
+    y = edge_segment_push_blocked(*args, node_block=bn)
+    yo = edge_segment_push_blocked_oracle(*args, node_block=bn)
+    yr = edge_segment_push_blocked_ref(*args, node_block=bn)
+    np.testing.assert_allclose(y, yo, rtol=ORACLE_RTOL, atol=0)
+    assert jnp.allclose(y, yr, rtol=1e-5, atol=1e-6)
+    assert bool(jnp.all(y[0, 3 * bn:] == 0))     # block 3 is never visited
+    kept = (src % bn == src - np.repeat(sb, te) * bn) & \
+        (dst % bn == dst - np.repeat(db, te) * bn)
+    assert 0 < kept.sum() < src.size              # some edges drop
+    np.testing.assert_allclose(float(y.sum()),
+                               float(jnp.sum(x[0, src[kept]])), rtol=1e-5)
 
 
 def test_blocked_push_matches_dense_push(graph):

@@ -1,6 +1,7 @@
 """Ahead-of-time compiles of the main-path Pallas kernels for a TPU v5e
 that is described, not attached: at real widths (1024 x 256 words; the
-graph push at the chip smoke's 2^20 nodes and node block 8192), with
+graph push at the chip smoke's 2^20 nodes and node block 8192, at the
+benchmark cell's 647,168 nodes and at node block 128), with
 ``interpret=False``. Mosaic refuses here what it would refuse on the chip
 (unsupported reductions and reshapes, misaligned blocks, SMEM and VMEM
 overflows), at no chip time.
@@ -19,6 +20,7 @@ M, W = 1024, 256                 # packed rows x 64-bit-word lanes
 NODES = 1 << 20
 NODE_BLOCK = 8192
 TILES = 20_000                   # ~ the smoke graph's edge tiles
+CELL_NODES, CELL_TILES = 647_168, 64_512
 TE = segsum.EDGE_TILE
 OFF = {"interpret": False}
 
@@ -75,6 +77,20 @@ CASES = {
             src, dst, sb, db, x, node_block=NODE_BLOCK, **OFF),
         lambda s: (_i32(s, TILES * TE), _i32(s, TILES * TE),
                    _i32(s, TILES), _i32(s, TILES), _f32(s, 1, NODES))),
+    # the pagerank20 benchmark cell's own shapes: graph500 scale 20 laid
+    # out in 8192-node blocks, 64,512 edge tiles of 512
+    "segsum_push_blocked_cell": (
+        lambda src, dst, sb, db, x: segsum.edge_segment_push_blocked(
+            src, dst, sb, db, x, node_block=NODE_BLOCK, **OFF),
+        lambda s: (_i32(s, CELL_TILES * TE), _i32(s, CELL_TILES * TE),
+                   _i32(s, CELL_TILES), _i32(s, CELL_TILES),
+                   _f32(s, 1, CELL_NODES))),
+    # node block 128: each block is a single row of 128 lanes
+    "segsum_push_blocked_bn128": (
+        lambda src, dst, sb, db, x: segsum.edge_segment_push_blocked(
+            src, dst, sb, db, x, node_block=128, **OFF),
+        lambda s: (_i32(s, 64 * TE), _i32(s, 64 * TE), _i32(s, 64),
+                   _i32(s, 64), _f32(s, 1, 1 << 16))),
     "frontier_update": (
         lambda p, v, d, lvl: segsum.frontier_update(p, v, d, lvl, **OFF),
         lambda s: (_f32(s, 1, NODES), _i32(s, 1, NODES), _i32(s, 1, NODES),

@@ -1,31 +1,36 @@
 """Unified memory-domain API: one pytree-native HRM object.
 
 The paper's core abstraction is a *memory domain*: a set of memory regions
-bound to a reliability tier, scrubbed and recovered as a unit. The seed
-exposed that as five loose pieces (``build_sidecar``/``scrub`` free
-functions, ``Scrubber``, ``RecoveryManager``, ``Injector``) hand-wired over
-a single ``"params"`` root. ``MemoryDomain`` replaces that wiring with one
-``jax.tree_util``-registered container owning
+bound to reliability tiers, scrubbed and recovered as a unit.
+``MemoryDomain`` is one ``jax.tree_util``-registered container owning
 
     payload          the protected state pytree — multiple roots at once
                      (``params``, ``opt/m``, ``opt/v``, ``kv_cache``)
-    sidecar          per-*tier* concatenated ECC/parity buffers
+    sidecar          per-*tier* ECC/parity buffers
     hard_error_map   live sticky (hard) errors, re-asserted on writes
     policy + plan    static region->tier assignment and buffer layout
 
 and a verb API: ``MemoryDomain.protect(state, policy)``, ``.scrub(step)``,
-``.recover(report, ...)``, ``.inject(rng, n, hard=)``, ``.refresh(state,
-paths=)``, ``.refresh_pages(pages, state)``, ``.stats()``.
+``.scrub_partial(cursor)``, ``.recover(report, ...)``, ``.inject(rng, n,
+hard=)``, ``.refresh(state, paths=)``, ``.refresh_pages(pages, state)``,
+``.stats()``.
 
-Execution model — tier-grouped batching: instead of the legacy per-leaf
-Python loop (one Pallas dispatch per leaf plus an O(n_leaves^2)
-``_set_leaf`` re-flatten), the payload is flattened **once**, same-tier
-leaves are concatenated into one packed ``(rows, LANES)`` buffer per tier,
-one Pallas kernel scrubs the whole tier, per-leaf slices are unpacked, and
-the payload is rebuilt with a single ``tree_unflatten``. Per-word ECC math
-is position-independent, so results are bit-identical to the legacy path
-(``tests/test_domain.py`` asserts this). The whole scrub/encode pass is a
-single jit-compiled computation cached per (domain structure, path subset).
+Execution model — row sets. Every protected leaf packs into ``(rows,
+LANES)`` 64-bit words; a tier's leaves lie end to end in its sidecar
+buffers, one sidecar row per packed row. Every pass is a ``RowSet``: per
+tier, the packed rows it reads and the sidecar rows it writes — whole
+leaves for ``scrub``/``refresh`` (or a ``paths`` subset), a cursor's
+window for ``scrub_partial``, whole leaves at a traced page index for
+``refresh_pages``. One scrub and one encode program are built from a row
+set, each one jit computation cached per (layout, row set): per tier, the
+rows are gathered into one buffer, the tier's kernel (``TIER_CODES``)
+runs over it, and the results go back where they came from; a tier's
+whole buffer passes through with no gather or scatter. Per-word ECC math
+is position-independent, so results are bit-identical to the legacy
+per-leaf path (``tests/test_domain.py``). Programs lower as
+``jit_<kind>_scrub``, ``_scrub_slice`` (a window), ``_encode`` (every
+whole buffer, or pages) and ``_encode_rows`` (a static subset), ``kind``
+the payload's root kind.
 
 Pad rows (to make row counts divide the kernel block) hold zero words whose
 code bits are also zero (every tier's code is linear), so padding
@@ -80,6 +85,21 @@ class LeafSpec(NamedTuple):
         return n * jnp.dtype(self.dtype).itemsize
 
 
+Piece = Tuple[LeafSpec, int, int]      # a leaf's packed rows a .. b
+
+
+class RowSet(NamedTuple):
+    """The rows one pass reads and writes: per tier, in tier order, the
+    leaf-local packed row ranges ``(leaf, a, b)``; they sit at rows
+    ``row_start + a .. row_start + b`` of the tier's sidecar buffers.
+    ``window``: a cursor's slice of a selection, which may cut leaves.
+    ``pages``: whole leaves read only at a traced index along their
+    second axis. Hashable: it keys the program caches."""
+    tiers: Tuple[Tuple[Tier, Tuple[Piece, ...]], ...]
+    window: bool = False
+    pages: bool = False
+
+
 def _key_str(entry) -> str:
     return str(getattr(entry, "key", getattr(entry, "name", entry)))
 
@@ -130,9 +150,8 @@ class DomainSpec:
         for s in leaves:
             if s.tier is not Tier.NONE:
                 grouped.setdefault(s.tier, []).append(s)
-        self.groups: Dict[Tier, Tuple[int, Tuple[LeafSpec, ...]]] = {
-            t: (_round_rows(sum(x.rows for x in ls)), tuple(ls))
-            for t, ls in grouped.items()}
+        self.groups: Dict[Tier, Tuple[LeafSpec, ...]] = {
+            t: tuple(ls) for t, ls in grouped.items()}
         self.by_path = {s.path: s for s in leaves}
         self.protectable = tuple(s for s in leaves if s.rows > 0)
         # root kind of the payload (``domain`` for a mixed one): it names
@@ -155,36 +174,74 @@ class DomainSpec:
     # ------------------------------------------------- subset selection
     def paths_key(self, paths: Optional[Iterable[str]]
                   ) -> Optional[Tuple[str, ...]]:
-        """Normalize a path subset into a hashable jit-cache key (in leaf
-        order); None selects every protected leaf."""
+        """Normalize a path subset into a hashable key (in leaf order) for
+        ``row_set``; None selects every protected leaf."""
         if paths is None:
             return None
         want = set(paths)
         return tuple(s.path for s in self.leaves
                      if s.path in want and s.tier is not Tier.NONE)
 
-    def select(self, key: Optional[Tuple[str, ...]]
-               ) -> Dict[Tier, Tuple[LeafSpec, ...]]:
-        if key is None:
-            return {t: g[1] for t, g in self.groups.items()}
-        want = set(key)
-        out = {}
-        for t, (_, ls) in self.groups.items():
-            sel = tuple(s for s in ls if s.path in want)
-            if sel:
-                out[t] = sel
-        return out
-
     def slices_aligned(self) -> bool:
         """Whether every protected leaf's slices along its second axis are
         whole packed rows, so ``refresh_pages`` can encode them alone."""
-        return all(_slice_rows(s) for ls in self.select(None).values()
+        return all(_slice_rows(s) for ls in self.groups.values()
                    for s in ls)
 
+    def row_set(self, key: Optional[Tuple[str, ...]], idx: int = 0,
+                slices: int = 1, pages: bool = False) -> RowSet:
+        """The rows of the ``key`` selection (``paths_key``): its leaves
+        whole or, with ``slices`` > 1, window ``idx``; with ``pages``, read
+        at a traced page index (``slices_aligned``). A window is cut per
+        tier from the selected leaves' rows laid end to end, so every tier
+        advances each call and finishes together after ``slices`` calls
+        (``MemoryDomain.scrub_partial``)."""
+        want = None if key is None else set(key)
+        tiers = []
+        for tier in sorted(self.groups, key=lambda t: t.value):
+            sel = [s for s in self.groups[tier]
+                   if want is None or s.path in want]
+            total = sum(s.rows for s in sel)
+            lo, hi = idx * total // slices, (idx + 1) * total // slices
+            pieces, off = [], 0
+            for s in sel:
+                a, b = max(lo - off, 0), min(hi - off, s.rows)
+                if a < b:
+                    pieces.append((s, a, b))
+                off += s.rows
+            if pieces:
+                tiers.append((tier, tuple(pieces)))
+        return RowSet(tuple(tiers), slices > 1, pages)
+
 
 # =====================================================================
-# tier-grouped batched kernels (traced helpers + jit caches)
+# row-set programs: one scrub and one encode, tier-batched
 # =====================================================================
+class TierCode(NamedTuple):
+    """A tier's kernels and stored sidecar, read by every pass."""
+    buf: str            # ``ecc`` (the scrub corrects) or ``par`` (detects)
+    dtype: Any          # the code words' stored dtype
+    encode: Callable    # (lo, hi, block_rows=) -> code words
+    scrub: Callable     # ECC: -> (lo, hi, ecc, corrected, uncorrectable);
+    #                     parity: -> (error bits, count)
+    copy: bool = False  # keeps the words too (MIRROR): parity errors are
+    #                     repaired from them; parity alone is detect only
+
+
+TIER_CODES: Dict[Tier, TierCode] = {
+    Tier.SECDED: TierCode("ecc", jnp.uint8, secded_encode_words,
+                          secded_scrub_words),
+    Tier.DECTED: TierCode("ecc", jnp.uint16, dected_encode_words,
+                          dected_scrub_words),
+    Tier.BURST: TierCode("ecc", jnp.uint16, burst_encode_words,
+                         burst_scrub_words),
+    Tier.PARITY_R: TierCode("par", jnp.uint8, parity_encode_words,
+                            parity_check_words),
+    Tier.MIRROR: TierCode("par", jnp.uint8, parity_encode_words,
+                          parity_check_words, copy=True),
+}
+
+
 def _concat_pad(arrs: List[jax.Array], padded: int) -> jax.Array:
     x = arrs[0] if len(arrs) == 1 else jnp.concatenate(arrs, axis=0)
     pad = padded - x.shape[0]
@@ -193,37 +250,10 @@ def _concat_pad(arrs: List[jax.Array], padded: int) -> jax.Array:
     return x
 
 
-def _gather_rows(buf: jax.Array, sel: Tuple[LeafSpec, ...],
-                 padded: int) -> jax.Array:
-    return _concat_pad([buf[s.row_start:s.row_start + s.rows] for s in sel],
-                       padded)
-
-
-def _scatter_rows(buf: jax.Array, sel: Tuple[LeafSpec, ...],
-                  new: jax.Array) -> jax.Array:
-    off = 0
-    for s in sel:
-        buf = buf.at[s.row_start:s.row_start + s.rows].set(
-            new[off:off + s.rows])
-        off += s.rows
-    return buf
-
-
-def _gather_packed(leaves, sel: Tuple[LeafSpec, ...], padded: int):
-    packed = [ops.pack_words(leaves[s.pos]) for s in sel]
-    lo = _concat_pad([p.lo for p in packed], padded)
-    hi = _concat_pad([p.hi for p in packed], padded)
-    return lo, hi
-
-
 def _parity_mask(err: jax.Array, like: jax.Array) -> jax.Array:
     """Packed (rows, LANES//8) parity-error bits -> (rows, LANES) bool."""
     bits = (err[..., :, None] >> jnp.arange(8, dtype=jnp.uint32)) & 1
     return bits.reshape(like.shape).astype(jnp.bool_)
-
-
-def _tier_order(groups: Dict[Tier, Any]) -> List[Tier]:
-    return sorted(groups, key=lambda t: t.value)
 
 
 def _block_rows(padded: int) -> int:
@@ -232,237 +262,6 @@ def _block_rows(padded: int) -> int:
     re-materializes every operand per grid step, so one grid step over the
     whole buffer is the fast path."""
     return padded if interpret_mode() else min(BLOCK_ROWS, padded)
-
-
-def _scrub_tier_buf(tier: Tier, lo, hi, pull, push, bm: int):
-    """Run one tier's scrub kernel over a packed (rows, LANES) word window.
-
-    ``pull(name, cast)`` / ``push(name, new, cast)`` read and write the
-    sidecar rows matching the window. Returns per-row
-    ``(lo2, hi2, corrected, uncorrectable, data_modified)`` —
-    ``data_modified=False`` for detect-only PARITY_R, whose counts land in
-    the uncorrectable column and whose data/sidecar are left untouched.
-    """
-    if tier is Tier.SECDED:
-        lo2, hi2, ecc2, c, u = secded_scrub_words(
-            lo, hi, pull("ecc", jnp.uint32), block_rows=bm)
-        push("ecc", ecc2, jnp.uint8)
-    elif tier is Tier.DECTED:
-        lo2, hi2, ecc2, c, u = dected_scrub_words(
-            lo, hi, pull("ecc", jnp.uint32), block_rows=bm)
-        push("ecc", ecc2, jnp.uint16)
-    elif tier is Tier.BURST:
-        lo2, hi2, ecc2, c, u = burst_scrub_words(
-            lo, hi, pull("ecc", jnp.uint32), block_rows=bm)
-        push("ecc", ecc2, jnp.uint16)
-    elif tier is Tier.PARITY_R:
-        _err, cnt = parity_check_words(
-            lo, hi, pull("par", jnp.uint32), block_rows=bm)
-        return lo, hi, jnp.zeros_like(cnt), cnt, False
-    elif tier is Tier.MIRROR:
-        err, _ = parity_check_words(
-            lo, hi, pull("par", jnp.uint32), block_rows=bm)
-        mask = _parity_mask(err, lo)
-        lo2 = jnp.where(mask, pull("copy_lo"), lo)
-        hi2 = jnp.where(mask, pull("copy_hi"), hi)
-        c = jnp.sum(mask.astype(jnp.int32), axis=1, keepdims=True)
-        u = jnp.zeros_like(c)
-    else:
-        raise ValueError(tier)
-    return lo2, hi2, c, u, True
-
-
-@functools.lru_cache(maxsize=None)
-def _compiled_scrub(spec: DomainSpec, key: Optional[Tuple[str, ...]]
-                    ) -> Callable:
-    """One jit program scrubbing every selected leaf, tier-batched.
-
-    fn(leaves_tuple, sidecar) -> (modified {pos: leaf}, new_sidecar,
-    corrected {path: n}, detected_uncorrectable {path: n}).
-    """
-    selected = spec.select(key)
-
-    def fn(leaves, sidecar):
-        mod: Dict[int, jax.Array] = {}
-        new_sc = {k: dict(v) for k, v in sidecar.items()}
-        corr: Dict[str, jax.Array] = {}
-        unc: Dict[str, jax.Array] = {}
-        for tier in _tier_order(selected):
-            sel = selected[tier]
-            full_padded, full_specs = spec.groups[tier]
-            is_full = len(sel) == len(full_specs)
-            padded = full_padded if is_full else _round_rows(
-                sum(s.rows for s in sel))
-            bm = _block_rows(padded)
-            sc = sidecar[tier.value]
-
-            def pull(name, cast=None):
-                buf = sc[name]
-                out = buf if is_full else _gather_rows(buf, sel, padded)
-                return out.astype(cast) if cast is not None else out
-
-            def push(name, new, cast=None):
-                new = new.astype(cast) if cast is not None else new
-                new_sc[tier.value][name] = new if is_full else \
-                    _scatter_rows(sc[name], sel, new[:sum(s.rows
-                                                          for s in sel)])
-
-            lo, hi = _gather_packed(leaves, sel, padded)
-            lo2, hi2, c, u, wrote = _scrub_tier_buf(tier, lo, hi, pull,
-                                                    push, bm)
-            off = 0
-            for s in sel:
-                sl = slice(off, off + s.rows)
-                if wrote:
-                    mod[s.pos] = ops.unpack_words(
-                        ops.Packed(lo2[sl], hi2[sl]), s.shape,
-                        jnp.dtype(s.dtype))
-                    corr[s.path] = jnp.sum(c[sl])
-                unc[s.path] = jnp.sum(u[sl])
-                off += s.rows
-        return mod, new_sc, corr, unc
-
-    return _jit_named(fn, f"{spec.kind}_scrub")
-
-
-@functools.lru_cache(maxsize=None)
-def _compiled_scrub_rows(spec: DomainSpec, key: Optional[Tuple[str, ...]],
-                         idx: int, slices: int) -> Callable:
-    """One jit program scrubbing row slice ``idx`` of ``slices`` over the
-    selection — the incremental-scrub cursor's compiled step.
-
-    The slice is taken per tier over the *virtual* concatenated row space
-    of the selected leaves (so every tier advances each call and finishes
-    together after ``slices`` calls), cut at packed-row boundaries: a row
-    holds whole 64-bit words of one leaf, so slicing never splits an ECC
-    codeword. Leaves overlapping the window are spliced at row
-    granularity — the corrected rows replace the leaf's packed rows and
-    the leaf is rebuilt, bit-identical outside the window.
-    """
-    selected = spec.select(key)
-
-    def fn(leaves, sidecar):
-        mod: Dict[int, jax.Array] = {}
-        new_sc = {k: dict(v) for k, v in sidecar.items()}
-        corr: Dict[str, jax.Array] = {}
-        unc: Dict[str, jax.Array] = {}
-        for tier in _tier_order(selected):
-            sel = selected[tier]
-            total = sum(s.rows for s in sel)
-            lo_r = (idx * total) // slices
-            hi_r = ((idx + 1) * total) // slices
-            if hi_r <= lo_r:
-                continue
-            # leaf pieces overlapping the window, in leaf-local rows
-            pieces = []
-            off = 0
-            for s in sel:
-                a, b = max(lo_r - off, 0), min(hi_r - off, s.rows)
-                if a < b:
-                    pieces.append((s, a, b))
-                off += s.rows
-            padded = _round_rows(hi_r - lo_r)
-            bm = _block_rows(padded)
-            sc = sidecar[tier.value]
-            packed = {s.path: ops.pack_words(leaves[s.pos])
-                      for s, _, _ in pieces}
-            lo = _concat_pad([packed[s.path].lo[a:b]
-                              for s, a, b in pieces], padded)
-            hi = _concat_pad([packed[s.path].hi[a:b]
-                              for s, a, b in pieces], padded)
-
-            def pull(name, cast=None):
-                out = _concat_pad(
-                    [sc[name][s.row_start + a:s.row_start + b]
-                     for s, a, b in pieces], padded)
-                return out.astype(cast) if cast is not None else out
-
-            def push(name, new, cast=None):
-                new = new.astype(cast) if cast is not None else new
-                buf = new_sc[tier.value][name]
-                o = 0
-                for s, a, b in pieces:
-                    buf = buf.at[s.row_start + a:s.row_start + b].set(
-                        new[o:o + (b - a)])
-                    o += b - a
-                new_sc[tier.value][name] = buf
-
-            lo2, hi2, c, u, wrote = _scrub_tier_buf(tier, lo, hi, pull,
-                                                    push, bm)
-            o = 0
-            for s, a, b in pieces:
-                sl = slice(o, o + (b - a))
-                if wrote:
-                    p = packed[s.path]
-                    mod[s.pos] = ops.unpack_words(
-                        ops.Packed(p.lo.at[a:b].set(lo2[sl]),
-                                   p.hi.at[a:b].set(hi2[sl])),
-                        s.shape, jnp.dtype(s.dtype))
-                    corr[s.path] = jnp.sum(c[sl])
-                unc[s.path] = jnp.sum(u[sl])
-                o += b - a
-        return mod, new_sc, corr, unc
-
-    return _jit_named(fn, f"{spec.kind}_scrub_slice")
-
-
-def _encode_tier(tier: Tier, lo, hi, bm: int) -> Dict[str, jax.Array]:
-    """One tier's fresh sidecar buffers for a packed (rows, LANES) window."""
-    if tier is Tier.SECDED:
-        return {"ecc": secded_encode_words(
-            lo, hi, block_rows=bm).astype(jnp.uint8)}
-    if tier is Tier.DECTED:
-        return {"ecc": dected_encode_words(
-            lo, hi, block_rows=bm).astype(jnp.uint16)}
-    if tier is Tier.BURST:
-        return {"ecc": burst_encode_words(
-            lo, hi, block_rows=bm).astype(jnp.uint16)}
-    if tier is Tier.PARITY_R:
-        return {"par": parity_encode_words(
-            lo, hi, block_rows=bm).astype(jnp.uint8)}
-    if tier is Tier.MIRROR:
-        return {"copy_lo": lo, "copy_hi": hi,
-                "par": parity_encode_words(
-                    lo, hi, block_rows=bm).astype(jnp.uint8)}
-    raise ValueError(tier)
-
-
-@functools.lru_cache(maxsize=None)
-def _compiled_encode(spec: DomainSpec, key: Optional[Tuple[str, ...]]
-                     ) -> Callable:
-    """One jit program (re-)encoding sidecar buffers for the selection.
-
-    Full selection: fn(leaves) -> sidecar. Subset: fn(leaves, sidecar) ->
-    sidecar with only the selected rows rewritten.
-    """
-    selected = spec.select(key)
-
-    def encode_tier(tier, leaves, sel, padded):
-        lo, hi = _gather_packed(leaves, sel, padded)
-        return _encode_tier(tier, lo, hi, _block_rows(padded))
-
-    if key is None:
-        def fn_full(leaves):
-            sc = {}
-            for tier in _tier_order(selected):
-                padded, _ = spec.groups[tier]
-                sc[tier.value] = encode_tier(tier, leaves, selected[tier],
-                                             padded)
-            return sc
-        return _jit_named(fn_full, f"{spec.kind}_encode")
-
-    def fn_partial(leaves, sidecar):
-        new_sc = {k: dict(v) for k, v in sidecar.items()}
-        for tier in _tier_order(selected):
-            sel = selected[tier]
-            total = sum(s.rows for s in sel)
-            fresh = encode_tier(tier, leaves, sel, _round_rows(total))
-            for name, new in fresh.items():
-                new_sc[tier.value][name] = _scatter_rows(
-                    sidecar[tier.value][name], sel, new[:total])
-        return new_sc
-
-    return _jit_named(fn_partial, f"{spec.kind}_encode_rows")
 
 
 def _slice_rows(s: LeafSpec) -> int:
@@ -479,50 +278,155 @@ def _slice_rows(s: LeafSpec) -> int:
     return rows if not rem else 0
 
 
+class _Gathered(NamedTuple):
+    """One tier's row set, gathered for its kernel."""
+    lo: jax.Array               # (padded rows, LANES) packed words
+    hi: jax.Array
+    packed: List[ops.Packed]    # each static piece's whole leaf, packed
+    at: Any                     # the rows' place in the sidecar buffers:
+    # None (the whole buffer), static (start, stop) spans, or traced rows
+
+
+def _gather(spec: DomainSpec, tier: Tier, leaves, pieces: Tuple[Piece, ...],
+            idx=None) -> _Gathered:
+    """Pack one tier's pieces into ``lo, hi`` padded by ``_round_rows``.
+    With a traced page index ``idx`` only the slices ``leaf[:, idx]`` are
+    packed, and their sidecar rows are computed from it."""
+    if idx is None:
+        packed = [ops.pack_words(leaves[s.pos]) for s, _, _ in pieces]
+        lo = [p.lo[a:b] for p, (_, a, b) in zip(packed, pieces)]
+        hi = [p.hi[a:b] for p, (_, a, b) in zip(packed, pieces)]
+        n = sum(b - a for _, a, b in pieces)
+        whole = pieces == tuple((s, 0, s.rows) for s in spec.groups[tier])
+        at = None if whole else tuple((s.row_start + a, s.row_start + b)
+                                      for s, a, b in pieces)
+    else:
+        packed, lo, hi, at = [], [], [], []
+        for s, _, _ in pieces:
+            r = _slice_rows(s)
+            p = ops.pack_words(leaves[s.pos][:, idx])
+            lo.append(p.lo[:s.shape[0] * idx.shape[0] * r])
+            hi.append(p.hi[:s.shape[0] * idx.shape[0] * r])
+            first = (jnp.arange(s.shape[0], dtype=jnp.int32)[:, None]
+                     * s.shape[1] + idx[None, :]) * r + s.row_start
+            at.append((first[:, :, None]
+                       + jnp.arange(r, dtype=jnp.int32)).reshape(-1))
+        at = jnp.concatenate(at)
+        n = at.shape[0]
+    padded = _round_rows(n)
+    return _Gathered(_concat_pad(lo, padded), _concat_pad(hi, padded),
+                     packed, at)
+
+
+def _pull(g: _Gathered, buf: jax.Array) -> jax.Array:
+    """The gathered rows of a sidecar buffer, padded as ``g.lo`` is."""
+    if g.at is None:
+        return buf
+    return _concat_pad([buf[a:b] for a, b in g.at], g.lo.shape[0])
+
+
+def _put(g: _Gathered, buf: Optional[jax.Array], new: jax.Array
+         ) -> jax.Array:
+    """``buf`` with the gathered rows set to ``new``'s; ``new`` itself
+    where the rows are the whole buffer."""
+    if g.at is None:
+        return new
+    if not isinstance(g.at, tuple):
+        return buf.at[g.at].set(new[:g.at.shape[0]])
+    o = 0
+    for a, b in g.at:
+        buf = buf.at[a:b].set(new[o:o + b - a])
+        o += b - a
+    return buf
+
+
 @functools.lru_cache(maxsize=None)
-def _compiled_encode_slices(spec: DomainSpec) -> Callable:
-    """One jit program re-encoding the sidecar rows of index slices along
-    the second axis of every protected leaf (each slice a whole number of
-    packed rows: ``DomainSpec.slices_aligned``).
+def _compiled_scrub(spec: DomainSpec, rows: RowSet) -> Callable:
+    """One jit program scrubbing a row set, tier-batched.
 
-    fn(leaves, sidecar, idx) -> sidecar, the sidecar donated so the
-    scatter updates its buffers in place. Only ``leaf[:, idx]`` is gathered
-    and packed, and its fresh code rows are scattered to the slices' rows;
-    a repeated index writes the same rows twice. The gather is cheap where
-    the second axis is not the most minor in device memory: see
-    ``serve.paged_kv`` for the pool layout that keeps it so on a TPU.
-    Every tier's code is per 64-bit word, so the result is the full
-    encode's, bit for bit, wherever only those slices changed. It is the
-    write-path encode narrowed, and lowers under the same name.
+    fn(leaves_tuple, sidecar) -> (modified {pos: leaf}, new_sidecar,
+    corrected {path: n}, detected_uncorrectable {path: n}). A leaf that
+    a window cuts is spliced back at row granularity, bit-identical
+    outside the window. PARITY_R's counts land in the uncorrectable
+    column, and its data and sidecar are left as they were.
     """
-    selected = spec.select(None)
-
-    def fn(leaves, sidecar, idx):
+    def fn(leaves, sidecar):
+        mod: Dict[int, jax.Array] = {}
         new_sc = {k: dict(v) for k, v in sidecar.items()}
-        for tier in _tier_order(selected):
-            los, his, at = [], [], []
-            for s in selected[tier]:
-                r = _slice_rows(s)
-                n = s.shape[0] * idx.shape[0] * r
-                p = ops.pack_words(leaves[s.pos][:, idx])
-                los.append(p.lo[:n])
-                his.append(p.hi[:n])
-                first = (jnp.arange(s.shape[0], dtype=jnp.int32)[:, None]
-                         * s.shape[1] + idx[None, :]) * r + s.row_start
-                at.append((first[:, :, None]
-                           + jnp.arange(r, dtype=jnp.int32)).reshape(-1))
-            at = jnp.concatenate(at)
-            total = at.shape[0]
-            padded = _round_rows(total)
-            fresh = _encode_tier(tier, _concat_pad(los, padded),
-                                 _concat_pad(his, padded),
-                                 _block_rows(padded))
+        corr: Dict[str, jax.Array] = {}
+        unc: Dict[str, jax.Array] = {}
+        for tier, pieces in rows.tiers:
+            code, sc = TIER_CODES[tier], sidecar[tier.value]
+            g = _gather(spec, tier, leaves, pieces)
+            bm = _block_rows(g.lo.shape[0])
+            stored = _pull(g, sc[code.buf]).astype(jnp.uint32)
+            if code.buf == "ecc":
+                lo, hi, ecc, c, u = code.scrub(g.lo, g.hi, stored,
+                                               block_rows=bm)
+                new_sc[tier.value]["ecc"] = _put(g, sc["ecc"],
+                                                 ecc.astype(code.dtype))
+            else:
+                err, u = code.scrub(g.lo, g.hi, stored, block_rows=bm)
+                if code.copy:
+                    mask = _parity_mask(err, g.lo)
+                    lo = jnp.where(mask, _pull(g, sc["copy_lo"]), g.lo)
+                    hi = jnp.where(mask, _pull(g, sc["copy_hi"]), g.hi)
+                    c = jnp.sum(mask.astype(jnp.int32), axis=1,
+                                keepdims=True)
+                    u = jnp.zeros_like(c)
+            o = 0
+            for (s, a, b), p in zip(pieces, g.packed):
+                sl = slice(o, o + b - a)
+                o += b - a
+                unc[s.path] = jnp.sum(u[sl])
+                if code.buf == "ecc" or code.copy:
+                    x = ops.Packed(lo[sl], hi[sl])
+                    if b - a < s.rows:
+                        x = ops.Packed(p.lo.at[a:b].set(x.lo),
+                                       p.hi.at[a:b].set(x.hi))
+                    mod[s.pos] = ops.unpack_words(x, s.shape,
+                                                  jnp.dtype(s.dtype))
+                    corr[s.path] = jnp.sum(c[sl])
+        return mod, new_sc, corr, unc
+
+    return _jit_named(fn, f"{spec.kind}_scrub"
+                      + ("_slice" if rows.window else ""))
+
+
+@functools.lru_cache(maxsize=None)
+def _compiled_encode(spec: DomainSpec, rows: RowSet) -> Callable:
+    """One jit program encoding a row set's sidecar rows, tier-batched.
+
+    Every tier's whole buffer (``spec.row_set(None)``): fn(leaves) ->
+    fresh sidecar. A static subset: fn(leaves, sidecar) -> sidecar with
+    only its rows rewritten. A page set: fn(leaves, sidecar, idx), the
+    sidecar donated so the scatter updates it in place; a repeated page
+    writes the same rows twice. Every tier's code is per 64-bit word, so
+    the rows come out as a full encode's, bit for bit, wherever only
+    they changed. The gather ``leaf[:, idx]`` is cheap where the second
+    axis is not the most minor in device memory: see ``serve.paged_kv``
+    for the pool layout that keeps it so on a TPU.
+    """
+    def fn(leaves, sidecar=None, idx=None):
+        new_sc = {k: dict(v) for k, v in (sidecar or {}).items()}
+        for tier, pieces in rows.tiers:
+            code = TIER_CODES[tier]
+            g = _gather(spec, tier, leaves, pieces, idx)
+            fresh = {code.buf: code.encode(
+                g.lo, g.hi, block_rows=_block_rows(g.lo.shape[0])
+            ).astype(code.dtype)}
+            if code.copy:
+                fresh.update(copy_lo=g.lo, copy_hi=g.hi)
+            out = new_sc.setdefault(tier.value, {})
             for name, new in fresh.items():
-                new_sc[tier.value][name] = \
-                    sidecar[tier.value][name].at[at].set(new[:total])
+                out[name] = _put(g, out.get(name), new)
         return new_sc
 
-    return _jit_named(fn, f"{spec.kind}_encode", donate_argnums=(1,))
+    if rows.pages:
+        return _jit_named(fn, f"{spec.kind}_encode", donate_argnums=(1,))
+    whole = rows == spec.row_set(None)
+    return _jit_named(fn, f"{spec.kind}_encode" + ("" if whole
+                                                   else "_rows"))
 
 
 # =====================================================================
@@ -606,9 +510,8 @@ class MemoryDomain:
                 tuple(int(d) for d in getattr(leaf, "shape", ())),
                 str(getattr(leaf, "dtype", "float32")), rows, start))
         spec = DomainSpec(policy, tuple(specs), treedef)
-        leaves = tuple(leaf for _, leaf in flat)
-        sidecar = _compiled_encode(spec, None)(leaves) if spec.groups else {}
-        return cls(state, sidecar, {}, spec)
+        dom = cls(state, {}, {}, spec)
+        return dom._encode(spec.row_set(None)) if spec.groups else dom
 
     # ------------------------------------------------------ accessors
     @property
@@ -663,17 +566,7 @@ class MemoryDomain:
             iv = self.spec.policy.scrub_interval
             if iv <= 0 or step % iv != 0:
                 return self, None
-        if not self.spec.groups:
-            return self, ScrubReport()
-        key = self.spec.paths_key(paths)
-        mod, new_sc, corr, unc = _compiled_scrub(self.spec, key)(
-            tuple(self._leaves()), self.sidecar)
-        leaves = self._leaves()
-        for pos, leaf in mod.items():
-            leaves[pos] = leaf
-        report = ScrubReport(corrected=dict(corr),
-                             detected_uncorrectable=dict(unc))
-        return self._rebuild(leaves, sidecar=new_sc), report
+        return self.scrub_partial(0, slices=1, paths=paths)
 
     def scrub_partial(self, cursor: int, *, slices: int = 8,
                       paths: Optional[Iterable[str]] = None
@@ -692,14 +585,13 @@ class MemoryDomain:
         ``scrub()`` would (corrections land as cursor reaches the row).
         Returns (domain, ScrubReport of this slice).
         """
-        if slices <= 1:
-            return self.scrub(paths=paths)
         if not self.spec.groups:
             return self, ScrubReport()
-        key = self.spec.paths_key(paths)
-        mod, new_sc, corr, unc = _compiled_scrub_rows(
-            self.spec, key, int(cursor) % slices, int(slices))(
-                tuple(self._leaves()), self.sidecar)
+        slices = max(int(slices), 1)
+        rows = self.spec.row_set(self.spec.paths_key(paths),
+                                 int(cursor) % slices, slices)
+        mod, new_sc, corr, unc = _compiled_scrub(self.spec, rows)(
+            tuple(self._leaves()), self.sidecar)
         leaves = self._leaves()
         for pos, leaf in mod.items():
             leaves[pos] = leaf
@@ -733,17 +625,8 @@ class MemoryDomain:
         clean-copy reload). One batched encode per tier; ``paths`` limits
         the rewrite to the touched leaves."""
         dom = self if state is None else self.adopt(state)
-        if not dom.spec.groups:
-            return dom
-        key = dom.spec.paths_key(paths)
-        leaves = tuple(dom._leaves())
-        if key is None:
-            sidecar = _compiled_encode(dom.spec, None)(leaves)
-        else:
-            if not key:
-                return dom
-            sidecar = _compiled_encode(dom.spec, key)(leaves, dom.sidecar)
-        return MemoryDomain(dom.payload, sidecar, dom.hard_errors, dom.spec)
+        rows = dom.spec.row_set(dom.spec.paths_key(paths))
+        return dom._encode(rows) if rows.tiers else dom
 
     def refresh_pages(self, pages, state=None) -> "MemoryDomain":
         """Re-encode sidecars after writes confined to index slices
@@ -764,13 +647,20 @@ class MemoryDomain:
         idx = np.asarray(pages, np.int32).reshape(-1)
         if not idx.size:
             return dom
-        n1 = min(s.shape[1] for ls in dom.spec.select(None).values()
-                 for s in ls)
+        n1 = min(s.shape[1] for ls in dom.spec.groups.values() for s in ls)
         if idx.min() < 0 or idx.max() >= n1:
             raise IndexError(f"page index out of range [0, {n1})")
-        sidecar = _compiled_encode_slices(dom.spec)(
-            tuple(dom._leaves()), dom.sidecar, jnp.asarray(idx))
-        return MemoryDomain(dom.payload, sidecar, dom.hard_errors, dom.spec)
+        return dom._encode(dom.spec.row_set(None, pages=True),
+                           jnp.asarray(idx))
+
+    def _encode(self, rows: RowSet, *idx) -> "MemoryDomain":
+        """Encode the sidecar rows of ``rows``, a page set at ``idx``."""
+        fn = _compiled_encode(self.spec, rows)
+        leaves = tuple(self._leaves())
+        sidecar = (fn(leaves) if rows == self.spec.row_set(None)
+                   else fn(leaves, self.sidecar, *idx))
+        return MemoryDomain(self.payload, sidecar, self.hard_errors,
+                            self.spec)
 
     # ------------------------------------------------------ injection
     def inject(self, rng, n: int = 1, *, hard: bool = False,

@@ -34,12 +34,9 @@ from typing import Dict, Iterable, Mapping, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.domain import TIER_CODES
 from repro.core.tiers import Tier
-from repro.kernels.burst import burst_encode_words, burst_scrub_words
-from repro.kernels.dected import dected_encode_words, dected_scrub_words
 from repro.kernels.ops import LANES
-from repro.kernels.parity import parity_check_words, parity_encode_words
-from repro.kernels.secded import secded_encode_words, secded_scrub_words
 
 STRIKE_CLASSES = ("single", "double_random", "double_adjacent")
 
@@ -110,18 +107,18 @@ def measure_class_rates(tier: Tier, strike: str, n_events: int = 128,
 
     if tier is Tier.NONE:
         return TierOutcomeRates(0.0, 0.0, 1.0)
+    code = TIER_CODES[tier]
+    stored = code.encode(jlo, jhi, **kw)
 
-    if tier is Tier.PARITY_R:
-        par = parity_encode_words(jlo, jhi, **kw)
-        _, cnt = parity_check_words(jblo, jbhi, par, **kw)
+    if code.buf == "par" and not code.copy:
+        _, cnt = code.scrub(jblo, jbhi, stored, **kw)
         detected = np.asarray(cnt)[:, 0] > 0
         # parity never repairs: undetected events are consumed corrupt
         n_det = int(detected.sum())
         return TierOutcomeRates(0.0, n_det / rows, (rows - n_det) / rows)
 
-    if tier is Tier.MIRROR:
-        par = parity_encode_words(jlo, jhi, **kw)
-        err, _ = parity_check_words(jblo, jbhi, par, **kw)
+    if code.copy:
+        err, _ = code.scrub(jblo, jbhi, stored, **kw)
         bitsmask = (np.asarray(err)[..., :, None]
                     >> np.arange(8, dtype=np.uint32)) & 1
         mask = bitsmask.reshape(lo.shape).astype(bool)
@@ -131,13 +128,7 @@ def measure_class_rates(tier: Tier, strike: str, n_events: int = 128,
         n_c = int(good.sum())
         return TierOutcomeRates(n_c / rows, 0.0, (rows - n_c) / rows)
 
-    encode, scrub = {
-        Tier.SECDED: (secded_encode_words, secded_scrub_words),
-        Tier.DECTED: (dected_encode_words, dected_scrub_words),
-        Tier.BURST: (burst_encode_words, burst_scrub_words),
-    }[tier]
-    ecc = encode(jlo, jhi, **kw)
-    lo2, hi2, _, _, unc = scrub(jblo, jbhi, ecc, **kw)
+    lo2, hi2, _, _, unc = code.scrub(jblo, jbhi, stored, **kw)
     detected = np.asarray(unc)[:, 0] > 0
     clean = ((np.asarray(lo2) == lo) & (np.asarray(hi2) == hi)).all(axis=1)
     corrected = clean & ~detected

@@ -13,7 +13,6 @@ import pytest
 import repro.serve.engine as engine_mod
 from repro.configs import get_tiny
 from repro.core import MemoryDomain, Tier
-from repro.core.domain import _compiled_encode
 from repro.core.trace import BoundStrike
 from repro.models import init_params
 from repro.runtime.serve_loop import serve_batch
@@ -38,7 +37,7 @@ def _pools(shape, seed):
 
 
 def _full_sidecar(dom):
-    return _compiled_encode(dom.spec, None)(tuple(dom._leaves()))
+    return dom.refresh().sidecar
 
 
 def _assert_sidecar_equal(got, want):
@@ -80,6 +79,22 @@ def test_refresh_pages_matches_full_encode(tier, page_tokens):
     assert all(b.is_deleted() for b in jax.tree.leaves(dom.sidecar))
     unlisted = protect().refresh_pages(np.array([0, 7, 3], np.int32),
                                        write(np.array([0, 7, 3, 5])))
+    _assert_sidecar_equal(unlisted.sidecar, got.sidecar)
+
+
+@pytest.mark.parametrize("tier", TIERS, ids=lambda t: t.value)
+def test_refresh_paths_matches_full_encode(tier):
+    """Write pool ``k`` and re-encode it alone (``refresh(paths=)``): the
+    sidecar is a full encode's, bit for bit. A changed pool ``v`` that
+    is not listed keeps its old code rows, so only ``k`` was encoded."""
+    shape = (2, 9, 8, 2, 64)
+    dom = MemoryDomain.protect(_pools(shape, 0), kv_policy(tier))
+    fresh = _pools(shape, 1)["kv_cache"]
+    old_v = dom.payload["kv_cache"]["v"]
+    got = dom.refresh({"kv_cache": {"k": fresh["k"], "v": old_v}},
+                      paths=["kv_cache/k"])
+    _assert_sidecar_equal(got.sidecar, _full_sidecar(got))
+    unlisted = dom.refresh({"kv_cache": fresh}, paths=["kv_cache/k"])
     _assert_sidecar_equal(unlisted.sidecar, got.sidecar)
 
 

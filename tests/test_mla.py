@@ -15,7 +15,6 @@ import pytest
 from repro.configs import get_tiny
 from repro.configs.base import MoEConfig
 from repro.core import Tier
-from repro.core.domain import _compiled_encode
 from repro.core.trace import BoundStrike
 from repro.models import attention as attn
 from repro.models import init_params
@@ -256,7 +255,7 @@ def _engine(model, **kw):
 
 
 def _sidecar_equal(dom):
-    want = _compiled_encode(dom.spec, None)(tuple(dom._leaves()))
+    want = dom.refresh().sidecar
     for tier in want:
         for name in want[tier]:
             np.testing.assert_array_equal(np.asarray(dom.sidecar[tier][name]),

@@ -15,8 +15,7 @@ from jax.profiler import ProfileData
 
 from repro.configs import get_tiny
 from repro.core import MemoryDomain, Tier, detect_recover_l
-from repro.core.domain import (_compiled_encode, _compiled_encode_slices,
-                               _compiled_scrub, _compiled_scrub_rows)
+from repro.core.domain import _compiled_encode, _compiled_scrub
 from repro.graph import graph_state, pagerank_scrubbed, powerlaw_graph
 from repro.graph.pagerank import _region_paths
 from repro.models import init_params
@@ -87,8 +86,15 @@ def _lower_serve(params, which):
     dom = eng.param_domain if which == "params_scrub" else eng.kv_domain
     leaves = tuple(dom._leaves())
     if which == "cache_encode":
-        return _compiled_encode(dom.spec, None).lower(leaves)
-    return _compiled_scrub(dom.spec, None).lower(leaves, dom.sidecar)
+        return _compiled_encode(dom.spec, dom.spec.row_set(None)).lower(
+            leaves)
+    if which == "cache_encode_pages":
+        assert dom.spec.slices_aligned()
+        return _compiled_encode(dom.spec, dom.spec.row_set(
+            None, pages=True)).lower(leaves, dom.sidecar,
+                                     jnp.zeros(SLOTS, jnp.int32))
+    return _compiled_scrub(dom.spec, dom.spec.row_set(None)).lower(
+        leaves, dom.sidecar)
 
 
 def _lower_graph(graph, which):
@@ -96,33 +102,27 @@ def _lower_graph(graph, which):
     dom = MemoryDomain.protect({"graph": state}, detect_recover_l())
     leaves = tuple(dom._leaves())
     if which == "graph_encode_rows":
-        key = dom.spec.paths_key(["graph/rank/rank"])
-        return _compiled_encode(dom.spec, key).lower(leaves, dom.sidecar)
-    key = dom.spec.paths_key(_region_paths(dom, ("graph/topology",
-                                                 "graph/rank")))
-    return _compiled_scrub_rows(dom.spec, key, 0, 3).lower(leaves,
-                                                           dom.sidecar)
+        rows = dom.spec.row_set(dom.spec.paths_key(["graph/rank/rank"]))
+        return _compiled_encode(dom.spec, rows).lower(leaves, dom.sidecar)
+    rows = dom.spec.row_set(dom.spec.paths_key(_region_paths(
+        dom, ("graph/topology", "graph/rank"))), 0, 3)
+    return _compiled_scrub(dom.spec, rows).lower(leaves, dom.sidecar)
+
+
+# the page-only KV refresh is the write-path encode, narrowed: it lowers
+# under the full encode's name, so the device trace counts it as the KV
+# refresh
+MODULE = {"cache_encode_pages": "cache_encode"}
 
 
 @pytest.mark.parametrize("which", ["serve_decode", "serve_prefill",
                                    "params_scrub", "cache_scrub",
                                    "cache_encode", "graph_scrub_slice",
-                                   "graph_encode_rows"])
+                                   "graph_encode_rows", "cache_encode_pages"])
 def test_program_module_names(params, graph, which):
     lowered = (_lower_graph(graph, which) if which.startswith("graph")
                else _lower_serve(params, which))
-    assert _module_name(lowered) == f"jit_{which}"
-
-
-def test_page_refresh_lowers_as_cache_encode(params):
-    """The page-only KV refresh is the write-path encode, narrowed: it
-    lowers under the full encode's name, so the device trace counts it
-    as the KV refresh."""
-    dom = _engine(params).kv_domain
-    assert dom.spec.slices_aligned()
-    lowered = _compiled_encode_slices(dom.spec).lower(
-        tuple(dom._leaves()), dom.sidecar, jnp.zeros(SLOTS, jnp.int32))
-    assert _module_name(lowered) == "jit_cache_encode"
+    assert _module_name(lowered) == f"jit_{MODULE.get(which, which)}"
 
 
 def test_domain_kind_from_roots(params):
